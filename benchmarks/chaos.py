@@ -157,8 +157,7 @@ def _serve_rows():
     rc = RunConfig(attn_q_chunk=32, attn_kv_chunk=32, scan_chunk=16)
     model = Model(cfg, rc)
     params = model.init(0)
-    ec = EngineConfig(page_size=8, num_pages=256, max_batch=3, max_seq=64,
-                      interpret=True)
+    ec = EngineConfig(page_size=8, num_pages=256, max_batch=3, max_seq=64)
 
     def make_engine():
         return ServingEngine(model, params, ec)

@@ -111,7 +111,8 @@ def bench_kernel_classes_ablation(B=24, pages_per_seq=64):
 
 def bench_engine_end_to_end(quick=True):
     """Serving engine: tokens/step metrics with the real model + kernel
-    (interpret mode — correctness path timing, not TPU wall time)."""
+    (the kernel runs interpreted off-TPU, so wall_s there is the
+    interpreter's, not the kernel's)."""
     from repro.configs import get_config
     from repro.models import Model, RunConfig
     from repro.serve import EngineConfig, ServingEngine
@@ -123,7 +124,7 @@ def bench_engine_end_to_end(quick=True):
     rows = []
     for policy in ("buddy_best", "page"):
         ec = EngineConfig(page_size=8, num_pages=256, max_batch=4,
-                          max_seq=128, interpret=True, alloc_policy=policy)
+                          max_seq=128, alloc_policy=policy)
         eng = ServingEngine(model, params, ec)
         rng = np.random.default_rng(0)
         for i in range(6):

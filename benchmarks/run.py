@@ -24,6 +24,7 @@ from typing import Any, Dict, List
 
 from . import _env  # noqa: F401  (must precede jax-importing modules)
 from . import chaos, paged_kernel, roofline_summary, tlb_suite
+from repro.compile_cache import enable_compile_cache
 from repro.core.sweep import resolve_backend
 from repro.scenarios import clear_materialized_cache
 
@@ -168,10 +169,11 @@ def main(argv=None) -> None:
     ap.add_argument("--backend", default="auto",
                     choices=("auto", "xla", "pallas"),
                     help="sweep execution backend (results are bit-exact "
-                         "across backends; 'auto' = pallas on TPU, xla "
-                         "elsewhere)")
+                         "across backends; 'auto' = xla; 'pallas' runs "
+                         "interpreted and is refused on a TPU)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     if args.no_cache:
         os.environ["REPRO_SWEEP_NO_CACHE"] = "1"
     only = set(args.only.split(",")) if args.only else None

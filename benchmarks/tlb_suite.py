@@ -173,9 +173,10 @@ def bench_synthetic(trace_len=150_000, n_pages=1 << 19, quick=True,
     return rows
 
 
-def bench_demand(trace_len=150_000, quick=True, max_pages=None,
-                 backend="auto"):
-    """Figure 8: per-benchmark relative misses on the demand mapping.
+def demand_plan(trace_len=150_000, quick=True, max_pages=None
+                ) -> Tuple[SweepPlan, Tuple[str, ...]]:
+    """The ``tlb_demand`` cells: the full method suite over each paper
+    analogue's demand mapping.  Returns ``(plan, benchmark names)``.
 
     Footprints are only capped in quick/smoke tiers; ``--full`` runs the
     declared paper-scale footprints (up to 4GB of virtual address space).
@@ -187,6 +188,13 @@ def bench_demand(trace_len=150_000, quick=True, max_pages=None,
     for name in benches:
         m, tr = _paper_world(name, trace_len, cap, trace_seed=3)
         _add_suite(plan, m, tr, name, ANCHOR_GRID_QUICK, psis=(2,))
+    return plan, benches
+
+
+def bench_demand(trace_len=150_000, quick=True, max_pages=None,
+                 backend="auto"):
+    """Figure 8: per-benchmark relative misses on the demand mapping."""
+    plan, benches = demand_plan(trace_len, quick, max_pages)
     res = plan.run(backend=backend)
     rows = []
     for name in benches:
@@ -316,6 +324,21 @@ def bench_scenarios(trace_len=120_000, quick=True,
     return rows
 
 
+def dynamic_plan(names: Sequence[str], trace_len=120_000,
+                 max_pages=MAX_PAGES_DEFAULT) -> SweepPlan:
+    """The ``tlb_dynamic`` cells of the named dynamic scenarios: the full
+    method suite over each world's epoch stream."""
+    plan = SweepPlan()
+    for name in names:
+        d = _scenario_world(name, trace_len, min(max_pages,
+                                                 DYNAMIC_MAX_PAGES))
+        # K is chosen by Algorithm 3 from the epoch-0 histogram — what the
+        # OS saw at launch; the events then degrade it, which is the point
+        _add_suite(plan, d.world, d.trace, name, ANCHOR_GRID_QUICK,
+                   psis=(2, 3), k_mapping=d.mapping)
+    return plan
+
+
 def bench_dynamic(trace_len=120_000, quick=True, max_pages=MAX_PAGES_DEFAULT,
                   backend="auto"):
     """Dynamic mapping worlds: mid-trace remaps with shootdown-correct TLBs.
@@ -329,15 +352,7 @@ def bench_dynamic(trace_len=120_000, quick=True, max_pages=MAX_PAGES_DEFAULT,
     large-reach designs pay for their coverage.
     """
     names = tuple(sc.name for sc in list_scenarios("dynamic"))
-    plan = SweepPlan()
-    for name in names:
-        d = _scenario_world(name, trace_len, min(max_pages,
-                                                 DYNAMIC_MAX_PAGES))
-        # K is chosen by Algorithm 3 from the epoch-0 histogram — what the
-        # OS saw at launch; the events then degrade it, which is the point
-        _add_suite(plan, d.world, d.trace, name, ANCHOR_GRID_QUICK,
-                   psis=(2, 3), k_mapping=d.mapping)
-    res = plan.run(backend=backend)
+    res = dynamic_plan(names, trace_len, max_pages).run(backend=backend)
     rows = []
     for name in names:
         cols = res[name]

@@ -3,7 +3,7 @@
 The paper's kind is memory-system efficiency at serving time, so this is the
 flagship e2e driver: a small LM served with continuous batching, a buddy
 paged KV cache, Algorithm-3-chosen coalescing classes and the coalesced
-Pallas paged-attention kernel (interpret mode on CPU).
+Pallas paged-attention kernel (compiled on a TPU, interpreted elsewhere).
 
 Run:  PYTHONPATH=src python examples/serve_paged.py [--requests 8]
 """
@@ -29,8 +29,7 @@ def main():
     model = Model(cfg, rc)
     params = model.init(0)
 
-    ec = EngineConfig(page_size=8, num_pages=256, max_batch=4, max_seq=128,
-                      interpret=True)
+    ec = EngineConfig(page_size=8, num_pages=256, max_batch=4, max_seq=128)
     engine = ServingEngine(model, params, ec)
 
     rng = np.random.default_rng(0)
@@ -45,7 +44,7 @@ def main():
     dt = time.time() - t0
 
     print(f"\nserved {args.requests} requests in {metrics['steps']} engine "
-          f"steps ({dt:.1f}s wall, interpret mode)")
+          f"steps ({dt:.1f}s wall, interpret={engine.interpret})")
     print(f"kernel classes K = {metrics['K']} (Algorithm 3 on the live "
           f"contiguity histogram)")
     print(f"DMA descriptors: {metrics['dma_descriptors']:.0f} coalesced vs "

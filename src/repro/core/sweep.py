@@ -23,11 +23,11 @@ caching.  Two backends consume that one definition:
   batches never pay them.
 * ``backend='pallas'`` (:mod:`repro.kernels.tlb_sweep`): a Pallas kernel
   whose grid maps lanes to program instances, keeps all TLB state in
-  scratch for the whole trace, and streams trace blocks in — eliminating
-  the HBM state round-trip per step on real accelerators (``interpret=True``
-  on CPU).
+  scratch for the whole trace, and streams trace blocks in.  It runs only
+  in the Pallas interpreter: the TPU compiler refuses it (see
+  :data:`PALLAS_TPU_REFUSAL`).
 
-``backend='auto'`` picks ``pallas`` on TPU and ``xla`` elsewhere.  Both
+``backend='auto'`` picks ``xla`` on every platform.  Both
 backends are bit-exact against the pure-python oracles
 :func:`~repro.core.simulator.run_method` /
 :func:`~repro.core.simulator.run_method_dynamic` for every block size
@@ -93,16 +93,33 @@ def _block_size(block_size: Optional[int]) -> int:
     return block_size
 
 
+#: The first refusal of the TPU compiler (jax 0.9.0, v5e) for the
+#: ``tlb_sweep`` kernel.  Behind it the kernel also reads scalars out of
+#: vector blocks and brings whole-footprint map/fill records into VMEM.
+PALLAS_TPU_REFUSAL = (
+    "the TPU compiler refuses the tlb_sweep Pallas kernel: \"The Pallas TPU "
+    "lowering currently requires that the last two dimensions of your "
+    "block shape are divisible by 8 and 128 respectively, or be equal to "
+    "the respective dimensions of the overall array\" (the per-lane "
+    "params block (1, 17) on an [L, 17] array); use backend='xla' on a TPU")
+
+
 def resolve_backend(backend: Optional[str]) -> str:
-    """Resolve the ``backend`` knob to the backend that actually runs:
-    ``'auto'``/``None`` picks ``pallas`` on TPU and ``xla`` elsewhere.
+    """Resolve the ``backend`` knob to the backend that actually runs.
+
+    ``'auto'``/``None`` is ``xla`` on every platform: the Pallas kernel
+    compiles for no chip (:data:`PALLAS_TPU_REFUSAL`), so on a TPU
+    ``'pallas'`` raises here, before any batch runs, rather than letting
+    the recovery ladder turn every batch into an unreported XLA run.
     Public so harnesses recording what ran (``benchmarks/run.py``) resolve
     it the same way ``run_sweep`` does."""
     if backend in (None, "auto"):
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
+        return "xla"
     if backend not in ("xla", "pallas"):
         raise ValueError(f"unknown sweep backend {backend!r} "
                          "(want 'auto', 'xla' or 'pallas')")
+    if backend == "pallas" and jax.default_backend() == "tpu":
+        raise ValueError(PALLAS_TPU_REFUSAL)
     return backend
 
 
@@ -620,9 +637,10 @@ def run_sweep(cells: Sequence[SweepCell], *, cache: bool = True,
     :func:`cell_key`) are loaded from ``cache_dir`` and skipped; set the
     ``REPRO_SWEEP_NO_CACHE`` env var or ``cache=False`` to bypass.
 
-    * ``backend`` — ``'auto'`` (pallas on TPU, xla elsewhere), ``'xla'``
-      (time-blocked vmapped scan; the CPU fast path), or ``'pallas'``
-      (the :mod:`repro.kernels.tlb_sweep` kernel; interpret mode off-TPU).
+    * ``backend`` — ``'auto'`` (= ``'xla'``), ``'xla'`` (time-blocked
+      vmapped scan), or ``'pallas'`` (the :mod:`repro.kernels.tlb_sweep`
+      kernel in interpret mode; refused on a TPU, see
+      :func:`resolve_backend`).
     * ``block_size`` — trace steps per block (default ``DEFAULT_BLOCK``,
       or the ``REPRO_SWEEP_BLOCK`` env var).  Execution detail only: block
       boundaries never change results.

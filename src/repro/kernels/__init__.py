@@ -8,3 +8,11 @@
   row, all TLB state resident in scratch for the whole trace
   (ops.run_lanes_pallas; select with ``run_sweep(backend='pallas')``).
 """
+import jax
+
+
+def default_interpret() -> bool:
+    """The one rule for a kernel's ``interpret`` knob when the caller leaves
+    it ``None``: compiled on a TPU, the Pallas interpreter everywhere else
+    (no other backend can compile a Mosaic kernel)."""
+    return jax.default_backend() != "tpu"

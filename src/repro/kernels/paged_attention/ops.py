@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...kvcache.block_table import descriptor_tables, dma_descriptor_count
+from .. import default_interpret
 from .paged_attention import merge_partials, paged_attention_class_pass
 
 
@@ -47,9 +48,13 @@ def _paged_attention_jit(q, k_pool, v_pool, kv_lens, desc_flat,
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     block_tables: np.ndarray, kv_lens: jax.Array,
                     *, page_size: int, K_classes: Sequence[int] = (),
-                    interpret: bool = True,
+                    interpret: Optional[bool] = None,
                     descriptors: Optional[Dict] = None) -> jax.Array:
-    """q: [B, H, D] → [B, H, D] decode attention over the paged KV pool."""
+    """q: [B, H, D] → [B, H, D] decode attention over the paged KV pool.
+
+    ``interpret`` defaults to :func:`repro.kernels.default_interpret`."""
+    if interpret is None:
+        interpret = default_interpret()
     classes = tuple(sorted(set(list(K_classes) + [0]), reverse=True))
     if descriptors is None:
         descriptors = build_descriptors(block_tables, classes)

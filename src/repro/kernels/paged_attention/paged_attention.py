@@ -17,9 +17,10 @@ Why this is the paper's mechanism and not just inspiration:
   reduction);
 * uncovered windows fall to the class-0 pass ↔ regular entries.
 
-VMEM budget: a class-k tile is (2^k·T, KVH, D) for K and V → e.g. k=4,
-T=64, KVH=8, D=128 ⇒ 2·16·64·8·128·2B = 4MB, well under the ~128MB VMEM of
-a v5e core; ``choose_kernel_classes`` caps k accordingly.
+VMEM budget: a class-k tile is (2^k·T, D) of one KV head for K and V →
+e.g. k=6, T=16, D=128 ⇒ 2·64·16·128·2B = 512KB per step, double-buffered
+well under a v5e core's default scoped VMEM; ``choose_kernel_classes``
+caps k at 6.
 """
 from __future__ import annotations
 
@@ -32,15 +33,20 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import default_interpret
+
 NEG_INF = -1e30
 
 
 def _class_kernel(win_idx_ref, cov_ref, len_ref,   # scalar prefetch
                   q_ref, k_ref, v_ref,             # VMEM blocks
                   o_ref, m_ref, l_ref,             # outputs (revisited)
-                  *, tokens_per_win: int, scale: float, kvh: int):
+                  *, tokens_per_win: int, scale: float):
+    """One (row b, KV head h, window j) step.  Every operand is 2-D: the
+    grid walks KV heads, so the G query heads of head h attend to one
+    [W, D] window without any in-kernel reshape (Mosaic refuses those)."""
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -51,93 +57,90 @@ def _class_kernel(win_idx_ref, cov_ref, len_ref,   # scalar prefetch
     @pl.when(cov_ref[b, j] == 1)
     def _attend():
         W = tokens_per_win
-        q = q_ref[0].astype(jnp.float32)             # [H, D]
-        k = k_ref[0].astype(jnp.float32)             # [W, KVH, D]
+        q = q_ref[0, 0].astype(jnp.float32)          # [G, D]
+        k = k_ref[0].astype(jnp.float32)             # [W, D]
         v = v_ref[0].astype(jnp.float32)
-        H, D = q.shape
-        G = H // kvh
-        qg = q.reshape(kvh, G, D)
         s = jax.lax.dot_general(
-            qg, k.transpose(1, 2, 0),                # [KVH, D, W]
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale   # [KVH, G, W]
-        pos = j * W + jax.lax.broadcasted_iota(jnp.int32, (1, 1, W), 2)
-        mask = pos < len_ref[b]
-        s = jnp.where(mask, s, NEG_INF)
+            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale    # [G, W]
+        pos = j * W + jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
+        s = jnp.where(pos < len_ref[b], s, NEG_INF)
 
-        m_prev = m_ref[0].astype(jnp.float32).reshape(kvh, G)
-        l_prev = l_ref[0].astype(jnp.float32).reshape(kvh, G)
-        o_prev = o_ref[0].astype(jnp.float32).reshape(kvh, G, D)
-
-        m_cur = jnp.max(s, axis=-1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new[..., None])
+        m_prev = m_ref[0, 0]                         # [G, 1]
+        l_prev = l_ref[0, 0]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1)
         pv = jax.lax.dot_general(
-            p, v.transpose(1, 0, 2),                 # [KVH, W, D]
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)      # [KVH, G, D]
-        o_new = o_prev * alpha[..., None] + pv
-
-        o_ref[0] = o_new.reshape(H, D).astype(o_ref.dtype)
-        m_ref[0] = m_new.reshape(H).astype(m_ref.dtype)
-        l_ref[0] = l_new.reshape(H).astype(l_ref.dtype)
+            p, v, dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)      # [G, D]
+        o_ref[0, 0] = o_ref[0, 0] * alpha + pv
+        m_ref[0, 0] = m_new
+        l_ref[0, 0] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
 
 
 def paged_attention_class_pass(
         q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         win_idx: jax.Array, covered: jax.Array, kv_lens: jax.Array,
         *, pages_per_block: int, page_size: int,
-        scale: Optional[float] = None, interpret: bool = True
+        scale: Optional[float] = None, interpret: Optional[bool] = None
         ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One class-k pass.
 
     q: [B, H, D]; pools: [n_pages, T, KVH, D]; win_idx/covered: [B, n_win]
     (physical window index / class-assignment mask); kv_lens: [B].
     Returns unnormalized (o [B,H,D] f32, m [B,H] f32, l [B,H] f32).
+    ``interpret`` defaults to :func:`repro.kernels.default_interpret`.
     """
+    if interpret is None:
+        interpret = default_interpret()
     B, H, D = q.shape
     n_pages, T, KVH, _ = k_pool.shape
+    G = H // KVH
     P2 = pages_per_block
     assert T == page_size
     assert n_pages % P2 == 0, (n_pages, P2)
     W = P2 * T
     n_win = win_idx.shape[1]
     scale = scale if scale is not None else 1.0 / np.sqrt(D)
+    d_out = D
+    if not interpret and D % 128:
+        # a compiled block's minor dim is whole 128-lane tiles: zero-pad the
+        # head dim (zeros add nothing to q.k or to p.v) and slice it back
+        pad = ((0, 0),) * 3 + ((0, -D % 128),)
+        q = jnp.pad(q, pad[1:])
+        k_pool, v_pool = jnp.pad(k_pool, pad), jnp.pad(v_pool, pad)
+        D = q.shape[-1]
 
-    kp = k_pool.reshape(n_pages // P2, W, KVH, D)
-    vp = v_pool.reshape(n_pages // P2, W, KVH, D)
+    # heads side by side on the minor axis: the (1, W, D) block of KV head
+    # h is then one strided window DMA whose last two dims tile as (8, 128)
+    kp = k_pool.reshape(n_pages // P2, W, KVH * D)
+    vp = v_pool.reshape(n_pages // P2, W, KVH * D)
+    qg = q.reshape(B, KVH, G, D)
 
-    grid = (B, n_win)
-    kernel = functools.partial(_class_kernel, tokens_per_win=W, scale=scale,
-                               kvh=KVH)
+    kernel = functools.partial(_class_kernel, tokens_per_win=W, scale=scale)
     out_shapes = (
-        jax.ShapeDtypeStruct((B, H, D), jnp.float32),
-        jax.ShapeDtypeStruct((B, H), jnp.float32),
-        jax.ShapeDtypeStruct((B, H), jnp.float32),
+        jax.ShapeDtypeStruct((B, KVH, G, D), jnp.float32),
+        jax.ShapeDtypeStruct((B, KVH, G, 1), jnp.float32),
+        jax.ShapeDtypeStruct((B, KVH, G, 1), jnp.float32),
     )
+    head_block = lambda b, h, j, *s: (b, h, 0, 0)   # noqa: E731
+    kv_block = pl.BlockSpec((1, W, D),
+                            lambda b, h, j, win, cov, ln: (win[b, j], 0, h))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, H, D), lambda b, j, *s: (b, 0, 0)),
-            pl.BlockSpec((1, W, KVH, D),
-                         lambda b, j, win, cov, ln: (win[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, W, KVH, D),
-                         lambda b, j, win, cov, ln: (win[b, j], 0, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, H, D), lambda b, j, *s: (b, 0, 0)),
-            pl.BlockSpec((1, H), lambda b, j, *s: (b, 0)),
-            pl.BlockSpec((1, H), lambda b, j, *s: (b, 0)),
-        ],
+        grid=(B, KVH, n_win),
+        in_specs=[pl.BlockSpec((1, 1, G, D), head_block), kv_block, kv_block],
+        out_specs=[pl.BlockSpec((1, 1, G, D), head_block),
+                   pl.BlockSpec((1, 1, G, 1), head_block),
+                   pl.BlockSpec((1, 1, G, 1), head_block)],
     )
-    return pl.pallas_call(
+    o, m, l = pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=out_shapes,
         interpret=interpret,
     )(win_idx.astype(jnp.int32), covered.astype(jnp.int32),
-      kv_lens.astype(jnp.int32), q, kp, vp)
+      kv_lens.astype(jnp.int32), qg, kp, vp)
+    return o.reshape(B, H, D)[..., :d_out], m.reshape(B, H), l.reshape(B, H)
 
 
 def merge_partials(parts) -> jax.Array:

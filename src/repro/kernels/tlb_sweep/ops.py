@@ -22,6 +22,7 @@ import jax
 import numpy as np
 
 from ...core.lane_program import build_block_plan, needs_switch_pass
+from .. import default_interpret
 from .tlb_sweep import N_PARAM_FIELDS, PARAM_KEYS, make_tlb_sweep_call
 
 _CALL_CACHE: Dict[Tuple[int, ...], object] = {}
@@ -54,12 +55,12 @@ def run_lanes_pallas(lanes, stacks, st0, seg_bounds, tb: int,
                      interpret: Optional[bool] = None):
     """Simulate one packed batch with the Pallas kernel.
 
-    ``interpret`` defaults to True off-TPU (the repo-wide kernel
-    convention); ``st0`` fixes the padded L2 geometry (state itself is
-    initialized in-kernel, in scratch).
+    ``interpret`` defaults to :func:`repro.kernels.default_interpret`;
+    ``st0`` fixes the padded L2 geometry (state itself is initialized
+    in-kernel, in scratch).
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     tb = effective_block(tb)
     lanes = {k: np.asarray(v) for k, v in lanes.items()}
     stacks = {k: np.asarray(v) for k, v in stacks.items()}

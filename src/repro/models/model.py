@@ -546,16 +546,21 @@ class Model:
 
     def decode_step_paged(self, params, state, tokens, kv_len, block_tables,
                           descriptors, *, page_size: int,
-                          K_classes: Tuple[int, ...], interpret: bool = True):
+                          K_classes: Tuple[int, ...],
+                          interpret: Optional[bool] = None):
         """One decode step against the PAGED KV cache (the paper's path).
 
         ``state``: as ``decode_step`` but attention positions hold
         {"pool_k","pool_v"} of shape [nsb, n_pages, T, KVH, D];
         ``block_tables``: [B, max_pages] int32 (shared by all layers);
         ``descriptors``: per-class (win_idx, covered) arrays from
-        ``repro.kernels.paged_attention.ops.build_descriptors``.
+        ``repro.kernels.paged_attention.ops.build_descriptors``;
+        ``interpret`` defaults to :func:`repro.kernels.default_interpret`.
         """
+        from ..kernels import default_interpret
         from ..kernels.paged_attention.ops import _paged_attention_jit
+        if interpret is None:
+            interpret = default_interpret()
         cfg, rc = self.cfg, self.rc
         cdt = jnp.dtype(rc.compute_dtype)
         B = tokens.shape[0]

@@ -18,12 +18,13 @@ This is where the paper's pieces meet end-to-end:
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels import default_interpret
 from ..kernels.paged_attention.ops import build_descriptors, dma_stats
 from ..kvcache.allocator import PagedKVAllocator
 from ..kvcache.block_table import choose_kernel_classes
@@ -49,7 +50,7 @@ class EngineConfig:
     psi: int = 3                    # |K| bound for Algorithm 3
     refresh_util_delta: float = 0.15
     alloc_policy: str = "buddy_best"
-    interpret: bool = True
+    interpret: Optional[bool] = None  # None: repro.kernels.default_interpret
     greedy: bool = True
 
 
@@ -74,6 +75,18 @@ class ServingEngine:
             "steps": 0, "tokens": 0, "dma_descriptors": 0,
             "dma_descriptors_page_granular": 0, "preemptions": 0,
             "kv_quarantined_pages": 0}
+        self.interpret = (default_interpret() if ec.interpret is None
+                          else ec.interpret)
+        # one compiled program per shape: prefill per prompt length, decode
+        # per class set K (the static knobs of the paged-attention passes);
+        # decode updates the KV pools in place of the donated old state
+        self._prefill_fn = jax.jit(model.prefill)
+        self._decode_fn = jax.jit(
+            model.decode_step_paged, donate_argnames=("state",),
+            static_argnames=("page_size", "K_classes", "interpret"))
+        # (request id, logits, index of its row) for every token the last
+        # step() chose: a request's prefill row first, then its decode row
+        self._step_logits: List[Tuple[int, jax.Array, Tuple[int, ...]]] = []
         self._init_state()
 
     # ------------------------------------------------------------------
@@ -85,9 +98,10 @@ class ServingEngine:
         for j in range(self.period):
             mk = _mixer_kind(cfg, j)
             if mk == "attn":
-                pool = jnp.zeros((self.nsb, ec.num_pages, ec.page_size,
-                                  cfg.n_kv_heads, cfg.head_dim), dt)
-                state[f"pos{j}"] = {"pool_k": pool, "pool_v": pool}
+                shape = (self.nsb, ec.num_pages, ec.page_size,
+                         cfg.n_kv_heads, cfg.head_dim)
+                state[f"pos{j}"] = {"pool_k": jnp.zeros(shape, dt),
+                                    "pool_v": jnp.zeros(shape, dt)}
             else:
                 from ..models.model import init_decode_state
                 full = init_decode_state(cfg, self.model.rc, B, 8, dt)
@@ -168,8 +182,7 @@ class ServingEngine:
         req = self.requests[rid]
         toks_list = req.prompt + req.generated
         toks = jnp.asarray(toks_list, jnp.int32)[None]
-        logits, states = jax.jit(self.model.prefill, static_argnames=())(
-            self.params, toks)
+        logits, states = self._prefill_fn(self.params, toks)
         bt = self.allocator.block_table(rid, self.max_pages)
         T = self.ec.page_size
         S = len(toks_list)
@@ -200,7 +213,9 @@ class ServingEngine:
             self.state[f"pos{j}"]["pool_v"] = pool_v.at[:, pages].set(
                 vpages.astype(pool_v.dtype))
         # seed first generated token greedily from the last prompt position
-        nxt = int(jnp.argmax(logits[0, S - 1, : self.cfg.vocab]))
+        last = logits[0, S - 1]
+        self._step_logits.append((rid, last, ()))
+        nxt = int(jnp.argmax(last[: self.cfg.vocab]))
         req.generated.append(nxt)
 
     @property
@@ -218,8 +233,15 @@ class ServingEngine:
                 self.sched.release(rid)
 
     # ------------------------------------------------------------------
+    def last_logits(self, rid: int) -> List[jax.Array]:
+        """The padded-vocab logits rows from which the last :meth:`step`
+        chose request ``rid``'s tokens, in order: the prefill row if it was
+        admitted in that step, then its decode row."""
+        return [lg[at] for r, lg, at in self._step_logits if r == rid]
+
     def step(self) -> bool:
         """One engine iteration: admit, decode one token for all running."""
+        self._step_logits = []
         self._admit()
         self._reap_finished()
         if not self.running:
@@ -244,16 +266,17 @@ class ServingEngine:
         self.metrics["dma_descriptors"] += st["descriptors_coalesced"]
         self.metrics["dma_descriptors_page_granular"] += st["pages"]
 
-        logits, self.state = self.model.decode_step_paged(
+        logits, self.state = self._decode_fn(
             self.params, self.state, jnp.asarray(toks), jnp.asarray(lens),
             tables, descriptors, page_size=ec.page_size,
-            K_classes=tuple(self.K), interpret=ec.interpret)
+            K_classes=tuple(self.K), interpret=self.interpret)
 
         nxt = np.asarray(jnp.argmax(logits[:, 0, : self.cfg.vocab], axis=-1))
         finished = []
         for rid in list(self.running):
             slot = self._slot_of(rid)
             req = self.requests[rid]
+            self._step_logits.append((rid, logits, (slot, 0)))
             req.generated.append(int(nxt[slot]))
             self.metrics["tokens"] += 1
             if len(req.generated) >= req.max_new_tokens:
@@ -263,6 +286,18 @@ class ServingEngine:
             self.sched.release(rid)
         self.metrics["steps"] += 1
         return bool(self.running or self.waiting)
+
+    def decode_hlo(self) -> str:
+        """HLO text of the compiled decode step for the current classes
+        ``K`` (a compiled Pallas kernel shows as ``tpu_custom_call``)."""
+        B = self.ec.max_batch
+        tables = np.full((B, self.max_pages), -1, np.int32)
+        return self._decode_fn.lower(
+            self.params, self.state, np.zeros((B, 1), np.int32),
+            np.zeros((B,), np.int32), tables,
+            build_descriptors(tables, self.K), page_size=self.ec.page_size,
+            K_classes=tuple(self.K), interpret=self.interpret
+        ).compile().as_text()
 
     def run_to_completion(self, max_steps: int = 10_000) -> Dict[str, float]:
         for _ in range(max_steps):

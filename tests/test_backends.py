@@ -118,6 +118,29 @@ def test_backend_name_validated():
         run_sweep([], backend="cuda")
 
 
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_auto_backend_is_xla(monkeypatch, platform):
+    """``auto`` never picks the Pallas kernel: no chip compiles it."""
+    from repro.core import sweep as sweep_mod
+    monkeypatch.setattr(sweep_mod.jax, "default_backend", lambda: platform)
+    assert sweep_mod.resolve_backend("auto") == "xla"
+    assert sweep_mod.resolve_backend(None) == "xla"
+
+
+def test_pallas_refused_on_tpu_before_any_batch(monkeypatch, cells):
+    """On a TPU ``backend='pallas'`` raises the compiler's refusal before
+    a batch runs, so the recovery ladder cannot turn it into XLA runs
+    reported as Pallas."""
+    from repro.core import sweep as sweep_mod
+    batches = []
+    monkeypatch.setattr(sweep_mod, "_BACKEND_FAULT_HOOK",
+                        lambda sub, backend: batches.append(backend))
+    monkeypatch.setattr(sweep_mod.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="refuses the tlb_sweep"):
+        run_sweep(cells, cache=False, backend="pallas")
+    assert batches == []
+
+
 def test_ref_backend_parity(worlds, oracles):
     """The step-at-a-time pure-JAX reference
     (``kernels/tlb_sweep/ref.py``) — the third leg of the parity matrix,

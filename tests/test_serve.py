@@ -150,3 +150,25 @@ def test_preemption_under_pool_pressure(model_and_params):
     assert all(r.state == "done" for r in eng.requests.values())
     for rid, want in enumerate(wants):
         assert eng.requests[rid].generated == want, rid
+
+
+def test_decode_step_compiles_nothing_after_warmup(model_and_params, rng):
+    """Prefill and decode are jitted once per engine: once the first step
+    has compiled them, a decode step with unchanged classes K compiles
+    nothing (before, every step re-traced the whole decode scan)."""
+    from repro.compile_cache import CompileLog
+    model, params = model_and_params
+    ec = EngineConfig(page_size=8, num_pages=64, max_batch=2, max_seq=64,
+                      interpret=True)
+    eng = ServingEngine(model, params, ec)
+    for _ in range(2):
+        eng.add_request(list(rng.integers(0, model.cfg.vocab, size=13)),
+                        max_new_tokens=6)
+    eng.step()                                   # admit, prefill, decode
+    K = list(eng.K)
+    with CompileLog() as log:
+        for _ in range(3):
+            assert eng.step()
+    assert eng.K == K, "K changed; the test needs a steady class set"
+    assert log.count == 0
+    assert eng.metrics["steps"] == 4
