@@ -21,7 +21,7 @@ Everything runs in this one process, on one chip, and nowhere else:
   widths and seeded random weights: 8 requests of 512 prompt tokens and
   32 new tokens, 16-token pages, a 1,024-page pool and the compiled
   paged-attention kernel.  The logits the engine chose each token from
-  (prefill and every decode step) of two requests are compared with a
+  (prefill and every decode step) of every request are compared with a
   float32 ``Model.prefill`` over the whole sequence at the highest matmul
   precision, and the compiled decode step must hold the kernel
   (``tpu_custom_call``).
@@ -75,8 +75,9 @@ ORACLE_FIELDS = ("accesses", "l1_hits", "l2_regular_hits",
 # On the CPU, at 24 layers of width 512, that gap came to a relative L2
 # error of 2e-2 and a largest error of 0.09 of the row's RMS.
 # * REL_L2_TOL bounds ||got - ref|| / ||ref||.  0.06 leaves bfloat16 about
-#   twice its expected error, while float8 (unit roundoff 2^-4 = 0.0625)
-#   would exceed it with the rounding of the final matmul's inputs alone.
+#   twice its expected error.  By its unit roundoff (2^-4 = 0.0625), float8
+#   should exceed it with the rounding of the final matmul's inputs alone;
+#   no run has measured that control.
 # * MAX_ABS_TOL bounds the largest single error in units of the row's RMS.
 #   Over a 92,544-logit row, bfloat16's spread reaches about five times its
 #   L2 error (0.15); a logit moved by a corrupt page, a stale position or a
@@ -183,12 +184,12 @@ def check_logits(got_rows: Sequence[np.ndarray], ref_rows: np.ndarray
 
 def serve(cfg: ModelConfig, *, seed: int, n_requests: int, prompt_len: int,
           new_tokens: int, page_size: int, num_pages: int, max_batch: int,
-          max_seq: int, rc: RunConfig = RunConfig(), interpret=None,
-          check_requests: Sequence[int] = (0, -1)) -> Dict:
+          max_seq: int, rc: RunConfig = RunConfig(), interpret=None
+          ) -> Dict:
     """Serve seeded random requests through ``ServingEngine``; keep the
-    logits rows it chose the tokens of ``check_requests`` from, and the
-    float32 reference rows at the same positions: one ``Model.prefill``
-    over each whole sequence, no kernel, no cache."""
+    logits rows it chose every request's tokens from, and the float32
+    reference rows at the same positions: one ``Model.prefill`` over each
+    whole sequence, no kernel, no cache."""
     t0 = time.perf_counter()
     model = Model(cfg, rc)
     with CompileLog() as init_log:
@@ -201,15 +202,14 @@ def serve(cfg: ModelConfig, *, seed: int, n_requests: int, prompt_len: int,
     prompts = rng.integers(0, cfg.vocab, size=(n_requests, prompt_len))
     rids = [eng.add_request(p.tolist(), max_new_tokens=new_tokens)
             for p in prompts]
-    check = [rids[i] for i in check_requests]
-    rows: Dict[int, list] = {rid: [] for rid in check}
+    rows: Dict[int, list] = {rid: [] for rid in rids}
     walls = {"init": time.perf_counter() - t0, "steps": []}
     with CompileLog() as log:
         more = True
         while more:
             t1 = time.perf_counter()
             more = eng.step()
-            for rid in check:
+            for rid in rids:
                 rows[rid] += [np.asarray(r[: cfg.vocab])
                               for r in eng.last_logits(rid)]
             walls["steps"].append(time.perf_counter() - t1)
@@ -220,7 +220,7 @@ def serve(cfg: ModelConfig, *, seed: int, n_requests: int, prompt_len: int,
                           .prefill)
     refs = {}
     with jax.default_matmul_precision("highest"):
-        for rid in check:
+        for rid in rids:
             req = eng.requests[rid]
             toks = jnp.asarray(req.prompt + req.generated[:-1], jnp.int32)
             logits, _ = ref_prefill(params, toks[None])

@@ -21,7 +21,8 @@ import numpy as np
 
 from ...kvcache.block_table import descriptor_tables, dma_descriptor_count
 from .. import default_interpret
-from .paged_attention import merge_partials, paged_attention_class_pass
+from .paged_attention import (merge_partials, paged_attention_class_pass,
+                              to_pool_layout)
 
 
 def build_descriptors(block_tables: np.ndarray, K_classes: Sequence[int]
@@ -35,6 +36,9 @@ def build_descriptors(block_tables: np.ndarray, K_classes: Sequence[int]
 def _paged_attention_jit(q, k_pool, v_pool, kv_lens, desc_flat,
                          *, page_size: int, classes: Tuple[int, ...],
                          interpret: bool):
+    """The class passes and their merge over pools already in the kernel's
+    layout (:func:`~.paged_attention.kv_pool_shape`), as serving keeps them.
+    """
     parts = []
     for i, k in enumerate(classes):
         win_idx, covered = desc_flat[2 * i], desc_flat[2 * i + 1]
@@ -52,7 +56,9 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     descriptors: Optional[Dict] = None) -> jax.Array:
     """q: [B, H, D] → [B, H, D] decode attention over the paged KV pool.
 
-    ``interpret`` defaults to :func:`repro.kernels.default_interpret`."""
+    Pools are ``[n_pages, T, KVH, D]``, the reference's layout; they are
+    copied into the kernel's layout on each call.  ``interpret`` defaults
+    to :func:`repro.kernels.default_interpret`."""
     if interpret is None:
         interpret = default_interpret()
     classes = tuple(sorted(set(list(K_classes) + [0]), reverse=True))
@@ -62,7 +68,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     for k in classes:
         wi, cov = descriptors[k]
         desc_flat += [jnp.asarray(wi), jnp.asarray(cov)]
-    return _paged_attention_jit(q, k_pool, v_pool, jnp.asarray(kv_lens),
+    return _paged_attention_jit(q, to_pool_layout(k_pool),
+                                to_pool_layout(v_pool), jnp.asarray(kv_lens),
                                 tuple(desc_flat), page_size=page_size,
                                 classes=classes, interpret=interpret)
 

@@ -551,7 +551,8 @@ class Model:
         """One decode step against the PAGED KV cache (the paper's path).
 
         ``state``: as ``decode_step`` but attention positions hold
-        {"pool_k","pool_v"} of shape [nsb, n_pages, T, KVH, D];
+        {"pool_k","pool_v"} of shape [nsb, *kv_pool_shape(n_pages, T, KVH,
+        D)] (``repro.kernels.paged_attention.paged_attention``'s layout);
         ``block_tables``: [B, max_pages] int32 (shared by all layers);
         ``descriptors``: per-class (win_idx, covered) arrays from
         ``repro.kernels.paged_attention.ops.build_descriptors``;
@@ -559,6 +560,7 @@ class Model:
         """
         from ..kernels import default_interpret
         from ..kernels.paged_attention.ops import _paged_attention_jit
+        from ..kernels.paged_attention.paged_attention import to_pool_layout
         if interpret is None:
             interpret = default_interpret()
         cfg, rc = self.cfg, self.rc
@@ -587,9 +589,11 @@ class Model:
             n_pool = st["pool_k"].shape[0]
             drop_page = jnp.where(lane_ok, page_of, n_pool)
             pk = st["pool_k"].at[drop_page, off_of].set(
-                k[:, 0].astype(st["pool_k"].dtype), mode="drop")
+                to_pool_layout(k[:, 0]).astype(st["pool_k"].dtype),
+                mode="drop")
             pv = st["pool_v"].at[drop_page, off_of].set(
-                v[:, 0].astype(st["pool_v"].dtype), mode="drop")
+                to_pool_layout(v[:, 0]).astype(st["pool_v"].dtype),
+                mode="drop")
             o = _paged_attention_jit(
                 q[:, 0], pk, pv, kv_len + 1, tuple(desc_flat),
                 page_size=page_size, classes=classes, interpret=interpret)

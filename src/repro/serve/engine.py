@@ -26,6 +26,8 @@ import numpy as np
 
 from ..kernels import default_interpret
 from ..kernels.paged_attention.ops import build_descriptors, dma_stats
+from ..kernels.paged_attention.paged_attention import (kv_pool_shape,
+                                                       to_pool_layout)
 from ..kvcache.allocator import PagedKVAllocator
 from ..kvcache.block_table import choose_kernel_classes
 from ..models.model import Model, block_period, n_superblocks, _mixer_kind
@@ -98,8 +100,8 @@ class ServingEngine:
         for j in range(self.period):
             mk = _mixer_kind(cfg, j)
             if mk == "attn":
-                shape = (self.nsb, ec.num_pages, ec.page_size,
-                         cfg.n_kv_heads, cfg.head_dim)
+                shape = (self.nsb, *kv_pool_shape(
+                    ec.num_pages, ec.page_size, cfg.n_kv_heads, cfg.head_dim))
                 state[f"pos{j}"] = {"pool_k": jnp.zeros(shape, dt),
                                     "pool_v": jnp.zeros(shape, dt)}
             else:
@@ -206,8 +208,8 @@ class ServingEngine:
             kpad = jnp.pad(k[:, :S], ((0, 0), (0, pad), (0, 0), (0, 0)))
             vpad = jnp.pad(v[:, :S], ((0, 0), (0, pad), (0, 0), (0, 0)))
             pages = jnp.asarray(bt[:n_full], jnp.int32)
-            kpages = kpad.reshape(self.nsb, n_full, T, *k.shape[2:])
-            vpages = vpad.reshape(self.nsb, n_full, T, *v.shape[2:])
+            kpages = to_pool_layout(kpad).reshape(self.nsb, n_full, T, -1)
+            vpages = to_pool_layout(vpad).reshape(self.nsb, n_full, T, -1)
             self.state[f"pos{j}"]["pool_k"] = pool_k.at[:, pages].set(
                 kpages.astype(pool_k.dtype))
             self.state[f"pos{j}"]["pool_v"] = pool_v.at[:, pages].set(

@@ -56,13 +56,14 @@ def _abstract(tree, sharding):
 @pytest.mark.parametrize("widths", list(WIDTHS))
 def test_paged_attention_compiles_for_v5e(one_chip, widths, classes):
     from repro.kernels.paged_attention.ops import _paged_attention_jit
+    from repro.kernels.paged_attention.paged_attention import kv_pool_shape
 
     B, H, KVH, D, page, n_pages, max_pages = WIDTHS[widths]
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    pool = sds((n_pages, page, KVH, D), jnp.bfloat16)
+    pool = sds(kv_pool_shape(n_pages, page, KVH, D), jnp.bfloat16)
     desc = []
     for k in classes:
         desc += [sds((B, max_pages >> k), jnp.int32),
