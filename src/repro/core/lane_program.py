@@ -48,6 +48,7 @@ from .page_table import (DynamicMapping, Mapping, MultiTenantMapping,
                          huge_page_backed, next_pow2 as _next_pow2)
 from .plane_layout import (FILL_REC_WIDTH, MAP_REC_WIDTH, PLANE_FIELDS,
                            PLANE_WIDTH)
+from ..spans import span
 from .simulator import (CLUS_SETS, CLUS_WAYS, CTLB_SETS, CTLB_WAYS, DP_TABLE,
                         HUGE, INVALID, KSUBR, L1_SETS, L1_WAYS,
                         L1H_SETS, L1H_WAYS, LAT_COAL, LAT_CTLB,
@@ -369,6 +370,10 @@ def pack_lanes(cells: Sequence["SweepCellLike"], device_count: int = 1):
     static tuple; a batch with no segmented lane collapses to one segment
     and never runs the shootdown/switch pass.  Returns ``(lanes, stacks,
     (L, max_sets, max_ways), seg_bounds)``.
+
+    Spans ``repro.sweep.pack.maps``, ``.fills``, ``.clusters`` and
+    ``.stacks`` (dirty records, the trace stack and the padded stacks)
+    time its sections; the lane parameters are the rest.
     """
     worlds: List = []
     world_index: Dict[int, int] = {}
@@ -389,67 +394,77 @@ def pack_lanes(cells: Sequence["SweepCellLike"], device_count: int = 1):
                        for m in p.sources))
     T = bucket_trace_len(max(t.shape[0] for t in traces))
 
-    # map records: one per (world, source mapping)
-    map_recs: List[np.ndarray] = []
-    map_rec_id: Dict[Tuple[int, int], int] = {}
-    for w, p in plans.items():
-        for e, m in enumerate(p.sources):
-            map_rec_id[(w, e)] = len(map_recs)
-            map_recs.append(_map_record(m, P))
+    with span("repro.sweep.pack.maps"):
+        # map records: one per (world, source mapping)
+        map_recs: List[np.ndarray] = []
+        map_rec_id: Dict[Tuple[int, int], int] = {}
+        for w, p in plans.items():
+            for e, m in enumerate(p.sources):
+                map_rec_id[(w, e)] = len(map_recs)
+                map_recs.append(_map_record(m, P))
 
-    # fill records: one per (world, source, fill profile)
-    fill_recs: List[np.ndarray] = []
-    fill_rec_id: Dict[Tuple[int, int, tuple], int] = {}
-    for c in cells:
-        w = world_index[id(c.mapping)]
-        key = _fill_profile_key(c.spec)
-        for e, m in enumerate(plans[w].sources):
-            fk = (w, e, key)
-            if fk not in fill_rec_id:
-                fill_rec_id[fk] = len(fill_recs)
-                fill_recs.append(_fill_profile(m, key, P))
-
-    # cluster bitmaps: one per (world, source).  The stack is always P wide
-    # (not 1) so suites with and without cluster lanes share an executable;
-    # the budget guard below shrinks it back for paper-scale footprints.
-    need_clus = any(c.spec.side == "cluster" for c in cells)
-    clus_wide = need_clus or P * 4 * REC_FLOOR <= REC_PAD_BUDGET
-    clus_recs: List[np.ndarray] = [np.zeros(P if clus_wide else 1, np.int32)]
-    clus_rec_id: Dict[Tuple[int, int], int] = {}
-    if need_clus:
+    with span("repro.sweep.pack.fills"):
+        # fill records: one per (world, source, fill profile)
+        fill_recs: List[np.ndarray] = []
+        fill_rec_id: Dict[Tuple[int, int, tuple], int] = {}
         for c in cells:
-            if c.spec.side != "cluster":
-                continue
             w = world_index[id(c.mapping)]
+            key = _fill_profile_key(c.spec)
             for e, m in enumerate(plans[w].sources):
-                if (w, e) not in clus_rec_id:
-                    rec = np.zeros(P, np.int32)
-                    rec[: m.n_pages] = cluster_bitmap(m)
-                    clus_rec_id[(w, e)] = len(clus_recs)
-                    clus_recs.append(rec)
+                fk = (w, e, key)
+                if fk not in fill_rec_id:
+                    fill_rec_id[fk] = len(fill_recs)
+                    fill_recs.append(_fill_profile(m, key, P))
 
-    # dirty records (prefix sums): one per (world, segment) whose plan
-    # carries a dirty bitmap (dynamic epochs e >= 1 with churn; nested
-    # segments whose composed view diverged at either level)
-    dirty_recs: List[np.ndarray] = [np.zeros(P + 1, np.int32)]
-    dirty_rec_id: Dict[Tuple[int, int], int] = {}
-    for w, p in plans.items():
-        for e, d in enumerate(p.dirty):
-            if d is None:
-                continue
-            dc = np.zeros(P + 1, np.int32)
-            nd = min(int(d.shape[0]), P)   # beyond P no entry can cover
-            np.cumsum(d[:nd], out=dc[1: nd + 1])
-            dc[nd + 1:] = dc[nd]
-            dirty_rec_id[(w, e)] = len(dirty_recs)
-            dirty_recs.append(dc)
+    with span("repro.sweep.pack.clusters"):
+        # cluster bitmaps: one per (world, source).  The stack is always P
+        # wide (not 1) so suites with and without cluster lanes share an
+        # executable; the budget guard below shrinks it back for
+        # paper-scale footprints.
+        need_clus = any(c.spec.side == "cluster" for c in cells)
+        clus_wide = need_clus or P * 4 * REC_FLOOR <= REC_PAD_BUDGET
+        clus_recs: List[np.ndarray] = [
+            np.zeros(P if clus_wide else 1, np.int32)]
+        clus_rec_id: Dict[Tuple[int, int], int] = {}
+        if need_clus:
+            for c in cells:
+                if c.spec.side != "cluster":
+                    continue
+                w = world_index[id(c.mapping)]
+                for e, m in enumerate(plans[w].sources):
+                    if (w, e) not in clus_rec_id:
+                        rec = np.zeros(P, np.int32)
+                        rec[: m.n_pages] = cluster_bitmap(m)
+                        clus_rec_id[(w, e)] = len(clus_recs)
+                        clus_recs.append(rec)
 
-    n_tr = len(traces)
-    if n_tr * T * 4 * 2 <= REC_PAD_BUDGET:
-        n_tr = max(REC_FLOOR, _next_pow2(n_tr))
-    trace_stack = np.zeros((n_tr, T), np.int32)
-    for i, t in enumerate(traces):
-        trace_stack[i, : t.shape[0]] = t
+    with span("repro.sweep.pack.stacks"):
+        # dirty records (prefix sums): one per (world, segment) whose plan
+        # carries a dirty bitmap (dynamic epochs e >= 1 with churn; nested
+        # segments whose composed view diverged at either level)
+        dirty_recs: List[np.ndarray] = [np.zeros(P + 1, np.int32)]
+        dirty_rec_id: Dict[Tuple[int, int], int] = {}
+        for w, p in plans.items():
+            for e, d in enumerate(p.dirty):
+                if d is None:
+                    continue
+                dc = np.zeros(P + 1, np.int32)
+                nd = min(int(d.shape[0]), P)   # beyond P no entry can cover
+                np.cumsum(d[:nd], out=dc[1: nd + 1])
+                dc[nd + 1:] = dc[nd]
+                dirty_rec_id[(w, e)] = len(dirty_recs)
+                dirty_recs.append(dc)
+
+        n_tr = len(traces)
+        if n_tr * T * 4 * 2 <= REC_PAD_BUDGET:
+            n_tr = max(REC_FLOOR, _next_pow2(n_tr))
+        trace_stack = np.zeros((n_tr, T), np.int32)
+        for i, t in enumerate(traces):
+            trace_stack[i, : t.shape[0]] = t
+        stacks = dict(maps=_pad_stack(map_recs),
+                      fills=_pad_stack(fill_recs, floor=FILL_REC_FLOOR),
+                      clus=_pad_stack(clus_recs),
+                      dirty=_pad_stack(dirty_recs), trace=trace_stack)
 
     # segment grid: union of all schedule boundaries, static per compile
     grid = sorted({int(b) for w in range(len(worlds))
@@ -531,10 +546,6 @@ def pack_lanes(cells: Sequence["SweepCellLike"], device_count: int = 1):
                                              and s.ctx_policy == "flush")
                 lanes["seg_fasid"][i, seg] = (p.recycled[e]
                                               and s.ctx_policy == "tag")
-    stacks = dict(maps=_pad_stack(map_recs),
-                  fills=_pad_stack(fill_recs, floor=FILL_REC_FLOOR),
-                  clus=_pad_stack(clus_recs), dirty=_pad_stack(dirty_recs),
-                  trace=trace_stack)
     return lanes, stacks, (L, max_sets, max_ways), seg_bounds
 
 

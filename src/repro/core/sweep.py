@@ -60,7 +60,6 @@ import dataclasses
 import hashlib
 import os
 import subprocess
-import time
 import zipfile
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -76,6 +75,7 @@ from .lane_program import (
 from .page_table import (DynamicMapping, Mapping, MultiTenantMapping,
                          NestedMapping, ParityWorld)
 from .simulator import MethodSpec, SimResult
+from ..spans import span
 
 # Default trace-steps-per-block of the time-blocked XLA backend.  Override
 # per call with ``run_sweep(..., block_size=...)`` or globally with the
@@ -323,11 +323,19 @@ def _simulate_lanes(lanes, stacks, st0, seg_bounds, backend="xla",
     always shard), else a single jitted scan.  ``pallas``: the
     :mod:`repro.kernels.tlb_sweep` kernel (interpret mode off-TPU).
     Returns ``(final_state, ppns)`` with at least ``counters`` and
-    ``cov_samples`` in the state dict."""
+    ``cov_samples`` in the state dict.
+
+    Spans: ``repro.sweep.upload`` (the single-device XLA path only; the
+    ``pmap`` and Pallas calls transfer their inputs inside
+    ``repro.sweep.scan``), ``repro.sweep.scan`` up to the outputs being
+    ready, and ``repro.sweep.readback``."""
     if backend == "pallas":
         from ..kernels.tlb_sweep import run_lanes_pallas
-        stF, ppns = run_lanes_pallas(lanes, stacks, st0, seg_bounds, tb)
-        return jax.device_get(stF), np.asarray(jax.device_get(ppns))
+        with span("repro.sweep.scan"):
+            stF, ppns = jax.block_until_ready(
+                run_lanes_pallas(lanes, stacks, st0, seg_bounds, tb))
+        with span("repro.sweep.readback"):
+            return jax.device_get(stF), np.asarray(jax.device_get(ppns))
     with_switch = needs_switch_pass(lanes)
     dev = jax.local_device_count()
     L = lanes["t_real"].shape[0]
@@ -335,16 +343,23 @@ def _simulate_lanes(lanes, stacks, st0, seg_bounds, backend="xla",
         def shard(x):
             return x.reshape((dev, L // dev) + x.shape[1:])
 
-        stF, ppns = _run_lanes_pmap(
-            {k: shard(v) for k, v in lanes.items()}, stacks,
-            {k: shard(v) for k, v in st0.items()}, seg_bounds, tb,
-            with_switch)
+        with span("repro.sweep.scan"):
+            stF, ppns = jax.block_until_ready(_run_lanes_pmap(
+                {k: shard(v) for k, v in lanes.items()}, stacks,
+                {k: shard(v) for k, v in st0.items()}, seg_bounds, tb,
+                with_switch))
         unshard = lambda x: np.asarray(x).reshape((L,) + x.shape[2:])  # noqa: E731
-        return ({k: unshard(v) for k, v in jax.device_get(stF).items()},
-                unshard(jax.device_get(ppns)))
-    stF, ppns = _run_lanes_jit(lanes, stacks, st0, seg_bounds, tb,
-                               with_switch)
-    return jax.device_get(stF), np.asarray(jax.device_get(ppns))
+        with span("repro.sweep.readback"):
+            return ({k: unshard(v) for k, v in jax.device_get(stF).items()},
+                    unshard(jax.device_get(ppns)))
+    with span("repro.sweep.upload"):
+        lanes, stacks, st0 = jax.block_until_ready(
+            jax.device_put((lanes, stacks, st0)))
+    with span("repro.sweep.scan"):
+        stF, ppns = jax.block_until_ready(_run_lanes_jit(
+            lanes, stacks, st0, seg_bounds, tb, with_switch))
+    with span("repro.sweep.readback"):
+        return jax.device_get(stF), np.asarray(jax.device_get(ppns))
 
 
 # ---------------------------------------------------------------------------
@@ -559,17 +574,29 @@ def _oracle_result(cell: SweepCell) -> SimResult:
 
 def _run_batch(sub: List[SweepCell], backend: str, tb: int
                ) -> List[SimResult]:
-    """Pack and simulate one batch; per-cell results in ``sub`` order."""
-    if _BACKEND_FAULT_HOOK is not None:
-        _BACKEND_FAULT_HOOK(sub, backend)
-    lanes, stacks, (L, max_sets, max_ways), seg_bounds = _pack_lanes(
-        sub, device_count=jax.local_device_count())
-    st0 = _init_batched_state(
-        L, max_sets, max_ways, lanes["pred0"], lanes["asid0"],
-        with_ctlb=any(c.spec.kind == "cache-tlb" for c in sub),
-        with_dp=any(c.spec.kind == "dead-protect" for c in sub))
-    stF, ppns = _simulate_lanes(lanes, stacks, st0, seg_bounds,
-                                backend=backend, tb=tb)
+    """Pack and simulate one batch; per-cell results in ``sub`` order.
+
+    Span ``repro.sweep.batch``, with the real trace steps ``steps_real``
+    (summed over lanes), the steps the scan runs ``steps_scanned`` (lanes
+    x the trace bucket) and ``bytes_uploaded`` (lanes, stacks and initial
+    state); inside it ``repro.sweep.pack`` around packing and the initial
+    state."""
+    with span("repro.sweep.batch") as batch:
+        if _BACKEND_FAULT_HOOK is not None:
+            _BACKEND_FAULT_HOOK(sub, backend)
+        with span("repro.sweep.pack"):
+            lanes, stacks, (L, max_sets, max_ways), seg_bounds = _pack_lanes(
+                sub, device_count=jax.local_device_count())
+            st0 = _init_batched_state(
+                L, max_sets, max_ways, lanes["pred0"], lanes["asid0"],
+                with_ctlb=any(c.spec.kind == "cache-tlb" for c in sub),
+                with_dp=any(c.spec.kind == "dead-protect" for c in sub))
+        batch.set(steps_real=int(lanes["t_real"].sum()),
+                  steps_scanned=L * stacks["trace"].shape[1],
+                  bytes_uploaded=sum(a.nbytes for d in (lanes, stacks, st0)
+                                     for a in d.values()))
+        stF, ppns = _simulate_lanes(lanes, stacks, st0, seg_bounds,
+                                    backend=backend, tb=tb)
     counters = np.asarray(stF["counters"])
     cov_samples = np.asarray(stF["cov_samples"])
     out = []
@@ -657,7 +684,7 @@ def run_sweep(cells: Sequence[SweepCell], *, cache: bool = True,
         sweep = run_sweep([SweepCell(s, d.mapping, d.trace) for s in specs])
         for r in sweep:                      # SimResult per cell, in order
             print(r.name, r.misses, r.cpi)
-        print(sweep.stats)                   # n_cells / cache_hits / wall_s
+        print(sweep.stats)                   # n_cells / cache_hits / ...
 
     Lanes are padded onto one array layout (max L2 geometry of the batch,
     inert ``K=-1`` alignment slots, power-of-two lane/trace shape buckets),
@@ -666,8 +693,10 @@ def run_sweep(cells: Sequence[SweepCell], *, cache: bool = True,
     :mod:`repro.core.lane_program` for the padding rules.  Batches mixing
     static and dynamic worlds are partitioned so purely-static cells never
     execute the epoch-segmented machinery.
+
+    Each packed batch is a span ``repro.sweep.batch`` (see
+    :func:`_run_batch`).
     """
-    t0 = time.time()
     backend = resolve_backend(backend)
     tb = _block_size(block_size)
     cache = cache and not os.environ.get("REPRO_SWEEP_NO_CACHE")
@@ -719,6 +748,5 @@ def run_sweep(cells: Sequence[SweepCell], *, cache: bool = True,
         tb_eff = effective_block(tb)
     stats = dict(n_cells=len(cells), cache_hits=hits,
                  simulated=len(todo), n_batches=len(batches),
-                 backend=backend, block=tb_eff,
-                 wall_s=round(time.time() - t0, 3), **fstats)
+                 backend=backend, block=tb_eff, **fstats)
     return SweepResult(results=results, stats=stats)  # type: ignore[arg-type]
