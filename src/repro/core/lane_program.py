@@ -22,9 +22,13 @@ Three layers live here:
    union of every method kind's datapath (L1, dual-probe THP, COLT window
    cover, the K-aligned probe chain with predictor, RMM ranges, clustered
    side-TLB, Algorithm-1 fills, LRU, latency and counters), selected per
-   lane by data.  :func:`shoot_lane` is the epoch-turnover translation
-   coherence pass.  Both operate on a plain dict of arrays for ONE lane;
-   backends decide where that state lives (scan carry vs kernel scratch).
+   lane by data.  Its state access has a one-hot form besides the point
+   form, and the XLA backend lowers that one for a TPU, because under
+   ``vmap`` point access becomes gathers and scatters whose TPU layouts
+   pad and copy whole state planes every step.  :func:`shoot_lane` is the
+   epoch-turnover translation coherence pass.  Both operate on a plain
+   dict of arrays for ONE lane; backends decide where that state lives
+   (scan carry vs kernel scratch).
 3. **The block plan** (:func:`build_block_plan`): the static timeline both
    backends execute — every epoch segment padded to a multiple of the block
    size, one shootdown flag per segment-entry block.  Block boundaries are
@@ -38,8 +42,10 @@ every lane must match :func:`repro.core.simulator.run_method` /
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -597,10 +603,76 @@ def init_batched_state(L: int, max_sets: int, max_ways: int, pred0,
     )
 
 
-def _cond_set(arr, idx, value, pred):
-    """In-place conditional point/row write (same trick as the oracle)."""
+# ---------------------------------------------------------------------------
+# State access: how the step reads a row of a state plane and writes a cell
+# ---------------------------------------------------------------------------
+#
+# Every read and write of a lane's state planes in :func:`step_access` goes
+# through one pair of primitives, in one of two forms with identical
+# results:
+#
+# * the point form indexes the plane (``plane[i]``, ``.at[idx].set``);
+# * the one-hot form compares an iota with the index on each indexed axis
+#   and reads by a masked sum, writes by a ``where``.  Under ``vmap`` the
+#   point form becomes batched gathers and scatters, whose TPU layouts pad
+#   the (ways, fields) minor pair to an 8x128 tile and copy whole planes
+#   between layouts every step; the one-hot form is elementwise and keeps
+#   the planes in one unpadded layout.
+#
+# The forms agree only for indices in range, since point indexing wraps
+# negative indices and clamps large ones and a mask matches nothing.  Every
+# index the step uses is in range by construction:
+#
+# * a set index is ``x & (n - 1)``, so it lies in ``[0, n)`` whatever the
+#   sign of ``x``, with ``n`` at most the plane's row count (``s1``,
+#   ``s1h``, ``sc``, ``sct``, ``sct_v`` and ``dp_idx`` take ``n`` from the
+#   plane; ``s2``, ``s2h`` and so ``fill_set`` and ``touch_set`` take the
+#   lane's ``l2_sets``, at most the batch's ``max_sets``);
+# * a way or entry comes from an ``argmin``/``argmax`` over that plane's
+#   axis;
+# * a field is a constant below the plane's width;
+# * the coverage slot is ``jnp.minimum(t // se, N_COV_SAMPLES - 1)`` with
+#   ``t >= 0`` and ``se >= 1``.
+
+
+def _point_read(plane, i):
+    return plane[i]
+
+
+def _point_write(arr, idx, value, pred):
+    """Conditional point/row write (same trick as the oracle)."""
     old = arr[idx]
     return arr.at[idx].set(jnp.where(pred, value, old))
+
+
+def _one_hot_read(plane, i):
+    hit = jnp.arange(plane.shape[0], dtype=jnp.int32) == i
+    hit = hit.reshape((-1,) + (1,) * (plane.ndim - 1))
+    return jnp.where(hit, plane, 0).sum(axis=0, dtype=plane.dtype)
+
+
+def _one_hot_write(arr, idx, value, pred):
+    """``_point_write`` as a masked ``where``; ``value`` broadcasts over
+    the axes ``idx`` leaves unindexed."""
+    idx = idx if isinstance(idx, tuple) else (idx,)
+    lead = arr.shape[:len(idx)]
+    hit = pred
+    for ax, i in enumerate(idx):
+        hit = hit & (jax.lax.broadcasted_iota(jnp.int32, lead, ax) == i)
+    hit = hit.reshape(lead + (1,) * (arr.ndim - len(idx)))
+    return jnp.where(hit, jnp.asarray(value, arr.dtype), arr)
+
+
+class StateAccess(NamedTuple):
+    """A row read ``read(plane, i)`` and a conditional write
+    ``write(arr, idx, value, pred)`` of the step's state planes."""
+
+    read: Callable
+    write: Callable
+
+
+POINT_ACCESS = StateAccess(_point_read, _point_write)
+ONE_HOT_ACCESS = StateAccess(_one_hot_read, _one_hot_write)
 
 
 # ---------------------------------------------------------------------------
@@ -608,7 +680,8 @@ def _cond_set(arr, idx, value, pred):
 # ---------------------------------------------------------------------------
 
 
-def step_access(lane, st, vpn, mrec, frec, bm, active):
+def step_access(lane, st, vpn, mrec, frec, bm, active,
+                access: StateAccess = POINT_ACCESS):
     """One translation of ONE lane; returns ``(new_state, out_ppn)``.
 
     * ``lane`` — dict of per-lane scalars (+ the ``kvals`` vector);
@@ -618,13 +691,16 @@ def step_access(lane, st, vpn, mrec, frec, bm, active):
     * ``mrec``/``frec`` — the 4-wide map/fill records at ``vpn`` (gathered
       by the caller from the live epoch's record stack);
     * ``bm`` — the cluster bitmap word at ``vpn``;
-    * ``active`` — False for padded steps: no state writes, no counters.
+    * ``active`` — False for padded steps: no state writes, no counters;
+    * ``access`` — the form of every state-plane read and write
+      (:data:`POINT_ACCESS` or :data:`ONE_HOT_ACCESS`; same results).
 
     The caller owns all gathers from the big record stacks — that is what
     lets the time-blocked backend hoist them to one bulk gather per block
     and the Pallas backend serve them from VMEM-resident per-segment
     blocks.
     """
+    read, write = access
     maxk = lane["kvals"].shape[0]
     kvals = lane["kvals"]
     use_pred = lane["use_pred"]
@@ -662,13 +738,13 @@ def step_access(lane, st, vpn, mrec, frec, bm, active):
 
     # ---------------- L1 (regular + gated 2MB array) ----------------
     s1 = vpn & jnp.int32(L1_SETS - 1)
-    l1row = st["l1"][s1]
+    l1row = read(st["l1"], s1)
     l1_ways_hit = (l1row[:, 0] == vpn) & (l1row[:, 3] == cur)
     l1_hit = l1_ways_hit.any()
     l1_way = jnp.argmax(l1_ways_hit)
     hv = vpn >> 9
     s1h = hv & jnp.int32(L1H_SETS - 1)
-    l1hrow = st["l1h"][s1h]
+    l1hrow = read(st["l1h"], s1h)
     h_ways_hit = (l1hrow[:, 0] == hv) & (l1hrow[:, 3] == cur)
     l1h_hit = is_thp & h_ways_hit.any()
     l1h_way = jnp.argmax(h_ways_hit)
@@ -678,7 +754,7 @@ def step_access(lane, st, vpn, mrec, frec, bm, active):
 
     # ---------------- L2 probes (all kinds, selected) ---------------
     s2 = (vpn >> k_hat) & set_mask
-    row = st["l2"][s2]                  # [W, 7]
+    row = read(st["l2"], s2)            # [W, 7]
     tags, kcls, contig, pbase = (row[:, TAG], row[:, KCLS],
                                  row[:, CONTIG], row[:, PPN])
     valid = (kcls != INVALID) & (row[:, L2_ASID] == cur)
@@ -694,7 +770,7 @@ def step_access(lane, st, vpn, mrec, frec, bm, active):
 
     # thp branch (dual-set probe on the same packed array)
     s2h = hv & set_mask
-    row_h = st["l2"][s2h]
+    row_h = read(st["l2"], s2h)
     huge_ways = (row_h[:, KCLS] == HUGE) & (row_h[:, TAG] == hv) & \
         (row_h[:, L2_ASID] == cur)
     reg_ways = (kcls == REGULAR) & (tags == vpn) & valid
@@ -777,7 +853,7 @@ def step_access(lane, st, vpn, mrec, frec, bm, active):
 
     cwd = vpn >> 3
     sc = cwd & jnp.int32(CLUS_SETS - 1)
-    crow = st["clus"][sc]               # [5, 4]
+    crow = read(st["clus"], sc)         # [5, 4]
     bit = (crow[:, 1] >> (vpn & 7)) & 1
     c_ways = (crow[:, 0] == cwd) & (bit == 1) & (crow[:, 3] == cur)
     cl_hit = has_cluster & c_ways.any()
@@ -785,7 +861,7 @@ def step_access(lane, st, vpn, mrec, frec, bm, active):
     # cache-backed tier (Victima lineage): probed only past an L1+L2 miss
     ctlb_sets = st["ctlb"].shape[0]     # degenerate (1, 1) when unused
     sct = vpn & jnp.int32(ctlb_sets - 1)
-    trow = st["ctlb"][sct]
+    trow = read(st["ctlb"], sct)
     t_ways = (trow[:, 0] == vpn) & (trow[:, 3] == cur)
     ctlb_hit = has_ctlb & ~l1_served & ~l2_hit & t_ways.any()
     ctlb_way = jnp.argmax(t_ways)
@@ -816,14 +892,14 @@ def step_access(lane, st, vpn, mrec, frec, bm, active):
     # re-referenced) bypasses the L2 fill; the counter saturates at 3
     dp_n = st["dp"].shape[0]            # degenerate (1,) when unused
     dp_idx = vpn & jnp.int32(dp_n - 1)
-    dp_ctr = st["dp"][dp_idx]
+    dp_ctr = read(st["dp"], dp_idx)
     dp_bypass = use_dead & walk & (dp_ctr == 0)
-    new["dp"] = _cond_set(st["dp"], dp_idx, jnp.minimum(dp_ctr + 1, 3),
+    new["dp"] = write(st["dp"], dp_idx, jnp.minimum(dp_ctr + 1, 3),
                           use_dead & wr)
 
     served_huge = is_thp & (fill_k == HUGE)
     fill_set = jnp.where(served_huge, s2h, s2)
-    frow = st["l2"][fill_set]
+    frow = jnp.where(served_huge, row_h, row)   # the row at fill_set
     valid_row = frow[:, KCLS] != INVALID
     score = jnp.where(way_ok,
                       jnp.where(valid_row, frow[:, LRU],
@@ -835,8 +911,8 @@ def step_access(lane, st, vpn, mrec, frec, bm, active):
                                frow[victim, CONTIG], 0)
     fill_vec = jnp.stack([fill_tag, fill_k, fill_contig, fill_ppn, t, cur,
                           fill_aux])
-    l2n = _cond_set(st["l2"], (fill_set, victim), fill_vec, fill_wr)
-    new["l2"] = _cond_set(l2n, (touch_set, tw, LRU), t,
+    l2n = write(st["l2"], (fill_set, victim), fill_vec, fill_wr)
+    new["l2"] = write(l2n, (touch_set, tw, LRU), t,
                           l2_hit & ~walk & ~l1_served & active)
     cov_delta = jnp.where(fill_wr, fill_contig - evicted_contig, 0)
 
@@ -844,13 +920,13 @@ def step_access(lane, st, vpn, mrec, frec, bm, active):
     mv = fill_wr & has_ctlb & valid_row[victim]
     ev_tag = frow[victim, TAG]
     sct_v = ev_tag & jnp.int32(ctlb_sets - 1)
-    vrow_t = st["ctlb"][sct_v][:, 0] >= 0
-    victim_t = jnp.argmin(jnp.where(vrow_t, st["ctlb"][sct_v][:, 2],
-                                    jnp.int32(NEG)))
+    vrow_ct = read(st["ctlb"], sct_v)
+    vrow_t = vrow_ct[:, 0] >= 0
+    victim_t = jnp.argmin(jnp.where(vrow_t, vrow_ct[:, 2], jnp.int32(NEG)))
     ctlb_vec = jnp.stack([ev_tag, frow[victim, PPN], t,
                           frow[victim, L2_ASID]])
-    ctn = _cond_set(st["ctlb"], (sct_v, victim_t), ctlb_vec, mv)
-    new["ctlb"] = _cond_set(ctn, (sct, ctlb_way, 2), t,
+    ctn = write(st["ctlb"], (sct_v, victim_t), ctlb_vec, mv)
+    new["ctlb"] = write(ctn, (sct, ctlb_way, 2), t,
                             ctlb_hit & active)
     cov_delta = cov_delta + jnp.where(
         mv, 1 - vrow_t[victim_t].astype(jnp.int32), 0)
@@ -862,8 +938,8 @@ def step_access(lane, st, vpn, mrec, frec, bm, active):
     ev_len = jnp.where(rmm_len[victim_r] > 0, rmm_len[victim_r], 0)
     rmm_wr = wr & has_rmm
     rmm_vec = jnp.stack([rs_v, rl_v, rmm_fill_ppn, t, cur])
-    rmmn = _cond_set(st["rmm"], victim_r, rmm_vec, rmm_wr)
-    new["rmm"] = _cond_set(rmmn, (sw, 3), t, rmm_hit & active)
+    rmmn = write(st["rmm"], victim_r, rmm_vec, rmm_wr)
+    new["rmm"] = write(rmmn, (sw, 3), t, rmm_hit & active)
     cov_delta = cov_delta + jnp.where(rmm_wr, rl_v - ev_len, 0)
 
     clusterable = bm != (jnp.int32(1) << (vpn & 7))
@@ -872,9 +948,9 @@ def step_access(lane, st, vpn, mrec, frec, bm, active):
     victim_c = jnp.argmin(jnp.where(vrow, crow[:, 2],
                                     jnp.int32(NEG)))
     cl_vec = jnp.stack([cwd, bm, t, cur])
-    cln = _cond_set(st["clus"], (sc, victim_c), cl_vec, fill_c)
+    cln = write(st["clus"], (sc, victim_c), cl_vec, fill_c)
     hit_cway = jnp.argmax((crow[:, 0] == cwd) & (crow[:, 3] == cur))
-    new["clus"] = _cond_set(cln, (sc, hit_cway, 2), t,
+    new["clus"] = write(cln, (sc, hit_cway, 2), t,
                             cl_hit & active)
 
     # ---------------- L1 fills --------------------------------------
@@ -882,8 +958,8 @@ def step_access(lane, st, vpn, mrec, frec, bm, active):
     vrh = l1hrow[:, 0] >= 0
     vich = jnp.argmin(jnp.where(vrh, l1hrow[:, 2], jnp.int32(NEG)))
     l1h_vec = jnp.stack([hv, fill_ppn, t, cur])
-    l1hn = _cond_set(st["l1h"], (s1h, vich), l1h_vec, do1h)
-    new["l1h"] = _cond_set(
+    l1hn = write(st["l1h"], (s1h, vich), l1h_vec, do1h)
+    new["l1h"] = write(
         l1hn, (s1h, l1h_way, 2), t,
         is_thp & l1_served & h_ways_hit.any() & ~l1_hit & active)
 
@@ -891,8 +967,8 @@ def step_access(lane, st, vpn, mrec, frec, bm, active):
     vr1 = l1row[:, 0] >= 0
     vic1 = jnp.argmin(jnp.where(vr1, l1row[:, 2], jnp.int32(NEG)))
     l1_vec = jnp.stack([vpn, ppn_true, t, cur])
-    l1n = _cond_set(st["l1"], (s1, vic1), l1_vec, do1)
-    new["l1"] = _cond_set(l1n, (s1, l1_way, 2), t, l1_hit & active)
+    l1n = write(st["l1"], (s1, vic1), l1_vec, do1)
+    new["l1"] = write(l1n, (s1, l1_way, 2), t, l1_hit & active)
 
     # ---------------- predictor update (gated) ----------------------
     upd = use_pred & active
@@ -920,7 +996,7 @@ def step_access(lane, st, vpn, mrec, frec, bm, active):
     new["t"] = t + act.astype(jnp.int32)
     se = lane["sample_every"]
     slot = jnp.minimum(t // se, N_COV_SAMPLES - 1)
-    new["cov_samples"] = _cond_set(st["cov_samples"], slot,
+    new["cov_samples"] = write(st["cov_samples"], slot,
                                    new["counters"][C_COV],
                                    (t % se == se - 1) & active)
 

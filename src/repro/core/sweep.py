@@ -20,7 +20,8 @@ caching.  Two backends consume that one definition:
   ops instead of ``TB × (~10 gathers + ~5 scatters)`` of vmapped
   point-scatter dispatches.  Epoch-turnover shootdowns run under a
   ``lax.cond`` on the (static-timeline) segment-entry blocks, so static
-  batches never pay them.
+  batches never pay them.  Lowered for a TPU, the step reads and writes
+  its state planes in the one-hot form (:func:`_lane_step`).
 * ``backend='pallas'`` (:mod:`repro.kernels.tlb_sweep`): a Pallas kernel
   whose grid maps lanes to program instances, keeps all TLB state in
   scratch for the whole trace, and streams trace blocks in.  It runs only
@@ -71,7 +72,8 @@ from .lane_program import (
     C_COAL, C_CYC, C_L1, C_PRED, C_PROBE, C_REG, C_SHOOT, C_WALK,
     LANE_SHARE_MAX, STEP_KEYS, build_block_plan,
     init_batched_state as _init_batched_state, needs_switch_pass,
-    pack_lanes as _pack_lanes, shoot_lane, step_access, switch_lane)
+    ONE_HOT_ACCESS, POINT_ACCESS, StateAccess, pack_lanes as _pack_lanes,
+    shoot_lane, step_access, switch_lane)
 from .page_table import (DynamicMapping, Mapping, MultiTenantMapping,
                          NestedMapping, ParityWorld)
 from .simulator import MethodSpec, SimResult
@@ -237,6 +239,28 @@ class SweepResult:
 # The XLA backend: one scan over TB-step blocks, body vmapped over lanes
 # ---------------------------------------------------------------------------
 
+def _platform_read(plane, i):
+    return jax.lax.platform_dependent(plane, i, default=POINT_ACCESS.read,
+                                      tpu=ONE_HOT_ACCESS.read)
+
+
+def _platform_write(arr, idx, value, pred):
+    return jax.lax.platform_dependent(arr, idx, value, pred,
+                                      default=POINT_ACCESS.write,
+                                      tpu=ONE_HOT_ACCESS.write)
+
+
+def _lane_step(lane, st, *x):
+    """:func:`~repro.core.lane_program.step_access` in the state-access form
+    of the platform the program is lowered for: one-hot on a TPU, whose
+    layouts for the point form's batched gathers and scatters copy whole
+    padded state planes every step; the point form elsewhere, where it is
+    the faster one.  Each primitive chooses for itself, so the step is
+    traced once and not once per form."""
+    return step_access(lane, st, *x,
+                       access=StateAccess(_platform_read, _platform_write))
+
+
 def _run_lanes_impl(lanes, stacks, st0, seg_bounds, tb, with_switch):
     """Time-blocked batched simulation of every lane.
 
@@ -269,7 +293,7 @@ def _run_lanes_impl(lanes, stacks, st0, seg_bounds, tb, with_switch):
         # step wide (unrolling it multiplies compile time for no run-time
         # gain on XLA — the win is the hoisted per-block gathers)
         def inner(st, x):
-            return step_access(lane, st, *x)
+            return _lane_step(lane, st, *x)
 
         return jax.lax.scan(inner, st, (vpn_b, mrec_b, frec_b, bm_b, act_b))
 

@@ -83,10 +83,14 @@ def oracles(worlds):
 
 
 @pytest.mark.parametrize("tb", [1, 3, 8])
-def test_xla_blocked_parity(cells, oracles, tb):
+@pytest.mark.parametrize("form", ["platform", "one_hot"])
+def test_xla_blocked_parity(request, cells, oracles, tb, form):
     """The time-blocked XLA backend is bit-exact vs the pure-python oracles
     for several block sizes, including the degenerate TB=1 (whose timeline
-    equals the step-at-a-time engine)."""
+    equals the step-at-a-time engine), in the platform's state-access form
+    and in the one-hot form a TPU lowering picks."""
+    if form == "one_hot":
+        request.getfixturevalue("one_hot_state_access")
     static_want, dyn_want = oracles
     sweep = run_sweep(cells, cache=False, backend="xla", block_size=tb)
     assert sweep.stats["backend"] == "xla"
@@ -173,6 +177,68 @@ def test_ref_backend_parity(worlds, oracles):
             np.testing.assert_array_equal(
                 np.asarray(ppns)[i, : trace.shape[0]], want.ppn,
                 err_msg=spec.name)
+
+
+# ---------------------------------------------------------------------------
+# State access: the one-hot form equals the point form
+# ---------------------------------------------------------------------------
+
+
+def _lane_planes():
+    """One lane's state planes, every kind's structures at full geometry."""
+    from repro.core.lane_program import init_batched_state
+    st = init_batched_state(1, 128, 8, np.zeros(1, np.int32),
+                            with_ctlb=True, with_dp=True)
+    return {k: v[0] for k, v in st.items() if v.ndim > 1}
+
+
+@pytest.mark.parametrize("plane", sorted(_lane_planes()))
+def test_one_hot_access_equals_point_access(plane):
+    """Each one-hot primitive returns what its point form returns, for a
+    row read at every row and a write at every index depth, with the
+    write's predicate true and false."""
+    import jax.numpy as jnp
+    from repro.core.lane_program import ONE_HOT_ACCESS, POINT_ACCESS
+    shape = _lane_planes()[plane].shape
+    r = np.random.default_rng(len(shape) * 1000 + shape[0])
+    arr = jnp.asarray(r.integers(-2**30, 2**30, shape, dtype=np.int32))
+    for i in range(shape[0]):
+        np.testing.assert_array_equal(ONE_HOT_ACCESS.read(arr, i),
+                                      POINT_ACCESS.read(arr, i))
+    for depth in range(1, len(shape) + 1):
+        for _ in range(4):
+            idx = tuple(jnp.int32(r.integers(0, n)) for n in shape[:depth])
+            value = jnp.asarray(r.integers(-2**30, 2**30, shape[depth:],
+                                           dtype=np.int32))
+            for pred in (True, False):
+                key = idx[0] if depth == 1 else idx
+                want = POINT_ACCESS.write(arr, key, value, jnp.bool_(pred))
+                got = ONE_HOT_ACCESS.write(arr, key, value, jnp.bool_(pred))
+                np.testing.assert_array_equal(got, want,
+                                              err_msg=str((depth, pred)))
+
+
+@pytest.mark.parametrize("form", ["platform", "one_hot"])
+def test_cpu_lowering_picks_the_point_form(request, form):
+    """Compiled for the CPU, the sweep's vmapped step keeps the point
+    form's scatters; the one-hot form has none."""
+    import jax
+    from repro.core import sweep as sweep_mod
+    from repro.core.lane_program import (STEP_KEYS, init_batched_state,
+                                         pack_lanes)
+    if form == "one_hot":
+        request.getfixturevalue("one_hot_state_access")
+    m = demand_mapping(1 << 9, seed=2)
+    tr = generate_trace("zipf", 0, 100, seed=7, mapping=m)
+    lanes, stacks, (L, sets, ways), _ = pack_lanes(
+        [SweepCell(base_spec(), m, tr)])
+    st0 = init_batched_state(L, sets, ways, lanes["pred0"])
+    x = (np.zeros(L, np.int32), stacks["maps"][0, :L],
+         stacks["fills"][0, :L], np.zeros(L, np.int32), np.ones(L, bool))
+    text = jax.jit(jax.vmap(sweep_mod._lane_step)).lower(
+        {k: lanes[k] for k in STEP_KEYS}, st0, *x).compile().as_text()
+    assert jax.default_backend() == "cpu"
+    assert ("scatter(" in text) == (form == "platform")
 
 
 # ---------------------------------------------------------------------------

@@ -147,9 +147,13 @@ def test_lane_matches_oracle_pallas(hand_cells, hand_oracle):
 
 
 @pytest.mark.parametrize("tb", [1, 8])
-def test_block_size_invariance(hand_cells, hand_oracle, tb):
+@pytest.mark.parametrize("form", ["platform", "one_hot"])
+def test_block_size_invariance(request, hand_cells, hand_oracle, tb, form):
     """Block boundaries never straddle a switch; results are identical for
-    any block size."""
+    any block size, in the platform's state-access form and in the one-hot
+    form a TPU lowering picks."""
+    if form == "one_hot":
+        request.getfixturevalue("one_hot_state_access")
     _, cells = hand_cells
     sweep = run_sweep(cells, cache=False, backend="xla", block_size=tb)
     for j, want in enumerate(hand_oracle):
