@@ -36,6 +36,7 @@ import numpy as np
 NEEDS_HOST_CPU = False
 REF_BUCKET = 512        # the reference's sequence lengths, in tokens
 POS_BUCKET = 128        # and its number of positions read
+ITL_PERCENTILES = (50, 90, 95, 99)
 
 
 # ---------------------------------------------------------------------------
@@ -232,10 +233,14 @@ def run(config: dict, traffic_spec: dict, ctx, interpret=None,
     import jax
     from repro.compile_cache import CompileLog
 
+    t_setup = [time.perf_counter()]
     params, eng = build(config, traffic_spec, ctx.seed, interpret)
+    jax.block_until_ready(params)
+    t_setup.append(time.perf_counter())
     traffic = Traffic(traffic_spec, config["vocab_size"], ctx.seed)
     n_steps = int(traffic_spec["max_steps_per_second"] * ctx.seconds) + 1
     warm_up(eng, traffic, n_steps, ctx.log)
+    t_setup.append(time.perf_counter())
     if fault is not None:
         fault(eng)
 
@@ -268,19 +273,44 @@ def run(config: dict, traffic_spec: dict, ctx, interpret=None,
 
     reap(False)
     jax.block_until_ready(eng.state)
+    t_setup.append(time.perf_counter())
+    ctx.log("[serve] set-up in the driver: weights and engine "
+            f"{t_setup[1] - t_setup[0]:.3f} s, warm-up "
+            f"{t_setup[2] - t_setup[1]:.3f} s, first admissions "
+            f"{t_setup[3] - t_setup[2]:.3f} s")
 
+    def need(rid: int) -> int:
+        req = eng.requests[rid]
+        return -(-(len(req.prompt) + req.max_new_tokens) //
+                 eng.ec.page_size)
+
+    device = jax.local_devices()[0]
     steps: List[dict] = []
+    held: List[int] = []          # pool pages held at each step's fullest
+    memory: List[dict] = []       # the device's allocator before each step
+    collections: List[tuple] = []  # (generation, start, end) of each
+    gc_start: List[float] = []     # collection of Python's garbage
+
+    def on_gc(phase: str, info: dict) -> None:
+        if phase == "start":
+            gc_start.append(time.perf_counter())
+        elif gc_start:
+            collections.append((info["generation"], gc_start.pop(),
+                                time.perf_counter()))
     gaps: List[float] = []
     tokens = 0
     tracing = ctx.trace
+    admitted_traced = False
     ctx.window_opens()
     t0 = time.perf_counter()
     if tracing:
         ctx.start_trace()
+    gc.callbacks.append(on_gc)
     with CompileLog() as log:
         while True:
             before = {rid: len(eng.requests[rid].generated) for rid in live}
             running = set(eng.running)
+            memory.append(device.memory_stats() or {})
             ts = time.perf_counter()
             with jax.profiler.TraceAnnotation("bench.serve.step"):
                 eng.step()
@@ -307,18 +337,49 @@ def run(config: dict, traffic_spec: dict, ctx, interpret=None,
                 t_prev[rid] = te
                 tokens += n1 - n0
             steps.append(rec)
+            # a request that finished in this step held its pages in it
+            held.append(sum(len(a.pages)
+                            for a in eng.allocator.seqs.values()) +
+                        sum(need(rid) for rid in live
+                            if eng.requests[rid].state == "done"))
             reap(True)
-            if tracing and te - t0 >= traffic_spec["trace_seconds"]:
+            admitted_traced |= tracing and bool(rec["prefill"])
+            # the trace lasts ``trace_seconds`` and holds an admission, so
+            # every serve.* reader finds something to read
+            if tracing and te - t0 >= traffic_spec["trace_seconds"] and \
+                    admitted_traced:
                 jax.block_until_ready(eng.state)
                 ctx.stop_trace()
                 tracing = False
             if te - t0 >= ctx.seconds:
                 break
+    gc.callbacks.remove(on_gc)
     window = steps[-1]["t1"] - t0
     ctx.stop_trace()
-    ctx.log(f"[serve] window {window!r} s: {len(steps)} steps, {tokens} "
-            f"tokens, {len(finished)} requests finished, {len(gaps)} "
-            f"inter-token gaps; compiles in window {log.count} "
+    itl_ms = dict(zip(ITL_PERCENTILES, (np.percentile(
+        gaps, ITL_PERCENTILES) * 1e3).tolist())) if gaps else {}
+    durations = [s["t1"] - s["t0"] for s in steps]
+    longest = int(np.argmax(durations))
+    mem = memory[longest]
+    a, b = steps[longest]["t0"], steps[longest]["t1"]
+    gc_in_longest = sum(max(0.0, min(e, b) - max(s, a))
+                        for _, s, e in collections)
+    gc_full = [e - s for g, s, e in collections if g == 2]
+    ctx.log(f"[serve] window {window!r} s: {len(steps)} steps "
+            f"({sum(bool(s['prefill']) for s in steps)} admitting, "
+            f"{sum(d > 1.0 for d in durations)} over 1 s; the longest, "
+            f"step {longest}, {durations[longest] * 1e3:.1f} ms, admitted "
+            f"prompts {steps[longest]['prefill']}, device bytes in use "
+            f"before it {mem.get('bytes_in_use')}, largest free block "
+            f"{mem.get('largest_free_block_bytes')}, garbage collection "
+            f"in it {gc_in_longest * 1e3:.1f} ms), {len(collections)} "
+            f"garbage collections ({len(gc_full)} of generation 2, the "
+            f"longest {max(gc_full, default=0.0) * 1e3:.1f} ms), "
+            f"pool pages held at a step's fullest: peak {max(held)}, mean "
+            f"{np.mean(held):.1f} of {eng.ec.num_pages}; "
+            f"{tokens} tokens, {len(finished)} requests finished, "
+            f"{len(gaps)} inter-token gaps (ms at percentiles {itl_ms}); "
+            f"compiles in window {log.count} "
             f"({log.seconds:.3f} s); preemptions "
             f"{eng.metrics['preemptions']}; K {eng.K}; DMA descriptors "
             f"{eng.metrics['dma_descriptors']} of "
@@ -338,8 +399,7 @@ def run(config: dict, traffic_spec: dict, ctx, interpret=None,
         observe(params, served)
     return dict(
         end_to_end={"tokens_per_s": tokens / window,
-                    "itl_p95_ms": float(np.percentile(gaps, 95)) * 1e3
-                    if gaps else None},
+                    "itl_p99_ms": itl_ms.get(99)},
         attempted=len(finished) + in_flight, failed=0,
         checks=checks, check_errors=errors, compiles_in_window=log.count,
         memory_peak_bytes=memory_peak,

@@ -217,6 +217,7 @@ def main(argv=None) -> int:
         os.environ["JAX_PLATFORMS"] = platforms + ",cpu"
     import jax
     jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+    t_import = time.time()
 
     dev = device_info(jax)
     if dev["platform"] not in ("tpu", "gpu") or dev["count"] < chips:
@@ -231,7 +232,9 @@ def main(argv=None) -> int:
     ctx = Context(args.seed, args.seconds, bool(args.trace), trace_dir)
     ctx.log(f"[device] {dev['platform']} {dev['kind']} x{dev['count']}; "
             f"workload {args.workload} seed {args.seed} seconds "
-            f"{args.seconds} trace {args.trace}; compile cache {CACHE_DIR}")
+            f"{args.seconds} trace {args.trace}; compile cache {CACHE_DIR}; "
+            f"JAX imported {t_import - T_START:.3f} s and its devices found "
+            f"{time.time() - T_START:.3f} s after start")
     try:
         result = execute(spec, driver, ctx, dev, peaks)
     except Exception:

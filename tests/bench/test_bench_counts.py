@@ -91,3 +91,33 @@ def test_mfu_reader_counts_prefill_and_decode_tokens():
             + counts.token_flops(TINY, 20)) / (2.0 * 197e12) * 100
     assert _reader("serve.mfu").read(trace, records, V5E) == \
         pytest.approx(want)
+
+
+def test_idle_share_reader_is_a_fraction():
+    trace = trace_reduce.Trace(window_s=2.0, busy_s=1.5, ops=[],
+                               modules=[], spans=[], host=[], busy=[])
+    read = _reader("serve.device_idle_share").read
+    assert read(trace, {"driver": "serve"}, V5E) == pytest.approx(0.25)
+    assert read(trace, {"driver": "sweep"}, V5E) is None
+
+
+def test_prefill_reader_leaves_out_every_step_s_own_programs():
+    # two traced steps: the first admits two requests (two prefills and
+    # their page writes), the second admits none; both decode, then slice
+    # and argmax their tokens (3 ms a step)
+    span = "bench.serve.step"
+    mods = [("jit_prefill", 0.000, 0.010), ("jit_prefill", 0.011, 0.010),
+            ("jit_scatter", 0.022, 0.004), ("jit_scatter", 0.027, 0.004),
+            ("jit_decode_step_paged", 0.032, 0.040),
+            ("jit_dynamic_slice", 0.073, 0.001), ("jit_argmax", 0.075, 0.002),
+            ("jit_decode_step_paged", 0.100, 0.040),
+            ("jit_dynamic_slice", 0.141, 0.001), ("jit_argmax", 0.143, 0.002)]
+    trace = trace_reduce.Trace(
+        window_s=0.15, busy_s=0.1, ops=[], modules=mods, host=[], busy=[],
+        spans=[(span, 0.0, 0.08), (span, 0.09, 0.06)])
+    read = _reader("serve.prefill_ms").read
+    assert read(trace, {}, V5E) == pytest.approx((10 + 4) * 1.0)
+    # no step without an admission: nothing to take the steps' own
+    # programs from, so nothing is read
+    trace.spans = trace.spans[:1]
+    assert read(trace, {}, V5E) is None
