@@ -1,31 +1,29 @@
 """Operations and bytes the algorithms need, computed from shapes.
 
-Kept with the benchmark so every PR counts the same work.  A dense
-decoder's configuration file gives the sizes (``hidden_size``,
-``num_hidden_layers``, ``num_attention_heads``, ``num_key_value_heads``,
-``head_dim``, ``intermediate_size``, ``vocab_size``).
+Kept with the benchmark so every PR counts the same work.  The
+configuration's architecture module (``bench/arch/``) gives the weights a
+token multiplies (``matmul_params``) and, for a token at a given context,
+the keys each attention layer reads with that layer's head counts
+(``attention_reads``); the counts here sum them.
 """
 from __future__ import annotations
 
 from typing import Iterable, Tuple
 
+from bench.arch import named
+
 
 def matmul_params(config: dict) -> int:
-    """Weights that every token multiplies: each layer's projections and
-    feed-forward, and the output head (the embedding is a lookup)."""
-    d = config["hidden_size"]
-    D = config["head_dim"]
-    q = config["num_attention_heads"] * D
-    kv = config["num_key_value_heads"] * D
-    layer = d * q + 2 * d * kv + q * d + 3 * d * config["intermediate_size"]
-    return config["num_hidden_layers"] * layer + d * config["vocab_size"]
+    """Weights that every token multiplies (the embedding is a lookup)."""
+    return named(config, "arch").matmul_params(config)
 
 
 def attention_flops(config: dict, context: int) -> int:
-    """Scores and weighted values of one query token over ``context``
-    keys, summed over layers: 2 multiply-adds per head dimension each."""
-    return (config["num_hidden_layers"] * 4 * config["num_attention_heads"]
-            * config["head_dim"] * context)
+    """Scores and weighted values of one query token at ``context``,
+    summed over layers: 2 multiply-adds per head dimension for each key
+    the layer reads."""
+    return sum(4 * heads * D * keys for keys, heads, _, D in
+               named(config, "arch").attention_reads(config, context))
 
 
 def token_flops(config: dict, context: int) -> int:
@@ -35,9 +33,10 @@ def token_flops(config: dict, context: int) -> int:
 
 
 def prefill_flops(config: dict, length: int) -> int:
-    """A prompt of ``length`` tokens, causal: token i attends over i + 1."""
+    """A prompt of ``length`` tokens, causal: token i is at context
+    i + 1."""
     return (2 * matmul_params(config) * length
-            + attention_flops(config, length * (length + 1) // 2))
+            + sum(attention_flops(config, n) for n in range(1, length + 1)))
 
 
 def paged_attention_work(config: dict, contexts: Iterable[int],
@@ -45,18 +44,17 @@ def paged_attention_work(config: dict, contexts: Iterable[int],
                          ) -> Tuple[int, int]:
     """(FLOPs, bytes) one decode step's paged attention needs: for every
     layer and every running request with ``n`` live tokens, read its query
-    and the K and V of those ``n`` tokens, write its output.  Pages the
-    pool holds beyond the live tokens, and windows that hold none, are no
-    part of the work."""
-    L = config["num_hidden_layers"]
-    H = config["num_attention_heads"]
-    KVH = config["num_key_value_heads"]
-    D = config["head_dim"]
+    and the K and V of the keys the layer reads at context ``n``, write
+    its output.  Pages the pool holds beyond those keys, and windows that
+    hold none, are no part of the work."""
+    reads = named(config, "arch").attention_reads
     flops = 0
     nbytes = 0
     for n in contexts:
         flops += attention_flops(config, n)
-        nbytes += L * (2 * n * KVH * D * kv_bytes + 2 * H * D * q_bytes)
+        nbytes += sum(2 * keys * kv_heads * D * kv_bytes
+                      + 2 * heads * D * q_bytes
+                      for keys, heads, kv_heads, D in reads(config, n))
     return flops, nbytes
 
 

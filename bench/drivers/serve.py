@@ -33,6 +33,8 @@ from typing import Dict, List
 
 import numpy as np
 
+from bench.arch import named
+
 NEEDS_HOST_CPU = False
 REF_BUCKET = 512        # the reference's sequence lengths, in tokens
 POS_BUCKET = 128        # and its number of positions read
@@ -157,29 +159,16 @@ def replay(snapshot: dict, ec, traffic: Traffic, n_steps: int) -> dict:
 # set-up
 # ---------------------------------------------------------------------------
 
-def model_config(config: dict):
-    """The program's configuration object for a dense decoder file."""
-    from repro.models.config import ModelConfig
-    return ModelConfig(
-        name=config["name"], family="dense",
-        n_layers=config["num_hidden_layers"], d_model=config["hidden_size"],
-        n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"],
-        head_dim=config["head_dim"], d_ff=config["intermediate_size"],
-        vocab=config["vocab_size"], rope_theta=float(config["rope_theta"]),
-        rms_eps=config["rms_norm_eps"],
-        tie_embeddings=config["tie_word_embeddings"])
-
-
 def build(config: dict, traffic: dict, seed: int, interpret=None):
-    """Weights from the seed, and an engine over them."""
+    """Weights from the seed, and an engine over them; the configuration
+    names its architecture module (``"arch"``)."""
     import jax
     from bench import weights
     from repro.models import Model, RunConfig
     from repro.serve import EngineConfig, ServingEngine
 
     dt = config["torch_dtype"]
-    model = Model(model_config(config), RunConfig(
+    model = Model(named(config, "arch").program_config(config), RunConfig(
         param_dtype=dt, compute_dtype=config["assumed"]["compute_dtype"]))
     params = weights.make(config, seed, jax.eval_shape(model.init), dt)
     eng = ServingEngine(model, params, EngineConfig(
@@ -439,10 +428,9 @@ def logit_gaps(ref_rows, chosen) -> np.ndarray:
 
 def reference_rows(config: dict, params, prompt, generated,
                    quantize: str = "none") -> np.ndarray:
-    """The reference's logits at every position where a token was
-    served: after the prompt's last token and after each served token
-    but the last."""
-    from bench.reference import internlm2
+    """The logits of the reference the configuration names
+    (``"reference"``) at every position where a token was served: after
+    the prompt's last token and after each served token but the last."""
     toks = list(prompt) + list(generated[:-1])
     pos = np.arange(len(prompt) - 1, len(toks))
     # padded to buckets so a few programs serve every length; the padding
@@ -450,8 +438,8 @@ def reference_rows(config: dict, params, prompt, generated,
     n, g = len(toks), len(pos)
     toks += [0] * (-n % REF_BUCKET)
     pos = np.concatenate([pos, np.full(-g % POS_BUCKET, pos[-1])])
-    return np.asarray(internlm2.logits(params, config, toks, pos,
-                                       quantize))[:g]
+    return np.asarray(named(config, "reference").logits(
+        params, config, toks, pos, quantize))[:g]
 
 
 def check(config: dict, traffic: dict, params, served: dict, seed: int,

@@ -1,14 +1,13 @@
-"""Seeded random weights for a dense decoder, made on the device.
+"""Seeded random weights for a served model, made on the device.
 
 One jitted call makes every leaf from the seed, in the dtype it is served
-in.  The tree is the layout the serving program takes (``embed``,
-``blocks.pos0.{ln1, ln2, attn.{wq, wk, wv, wo}, mlp.{w_gate, w_up,
-w_down}}``, ``final_norm``, ``lm_head``; layers stacked on the leading
-axis); :func:`make` checks it leaf by leaf against the program's abstract
-parameters.  Rows of the vocabulary past ``vocab_size`` (the program pads
-it) are zero.  Matrices are normal with standard deviation
-1/sqrt(fan-in); the embedding is normal with standard deviation 1; the
-norms' gains are 1.
+in.  The configuration's architecture module (``bench/arch/``) lists the
+leaves of the tree the serving program takes, each with its unpadded shape
+and its init; :func:`make` checks that list leaf by leaf against the
+program's abstract parameters.  Leaf ``i`` of the sorted paths draws from
+the seed's key folded with ``i``.  Rows of the vocabulary past
+``vocab_size`` (the program pads it) are zero.  An init is ``"ones"`` or
+``("normal", fan_in)``: normal with standard deviation 1/sqrt(fan_in).
 """
 from __future__ import annotations
 
@@ -18,23 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-
-def logical_shapes(config: dict) -> dict:
-    """Every leaf's unpadded shape, keyed by its path."""
-    d = config["hidden_size"]
-    L = config["num_hidden_layers"]
-    D = config["head_dim"]
-    q = config["num_attention_heads"] * D
-    kv = config["num_key_value_heads"] * D
-    ff = config["intermediate_size"]
-    V = config["vocab_size"]
-    return {
-        "embed": (V, d), "lm_head": (d, V), "final_norm": (d,),
-        "blocks.pos0.ln1": (L, d), "blocks.pos0.ln2": (L, d),
-        "blocks.pos0.attn.wq": (L, d, q), "blocks.pos0.attn.wk": (L, d, kv),
-        "blocks.pos0.attn.wv": (L, d, kv), "blocks.pos0.attn.wo": (L, q, d),
-        "blocks.pos0.mlp.w_gate": (L, d, ff), "blocks.pos0.mlp.w_up":
-            (L, d, ff), "blocks.pos0.mlp.w_down": (L, ff, d)}
+from bench.arch import named
 
 
 def _flatten(tree, prefix=""):
@@ -62,11 +45,11 @@ def _unflatten(flat: dict) -> dict:
 @functools.partial(jax.jit, static_argnames=("shapes", "dtype"))
 def _make(key, *, shapes, dtype):
     flat = {}
-    for i, (path, logical, padded) in enumerate(shapes):
-        if path.endswith(("ln1", "ln2", "final_norm")):
+    for i, (path, logical, padded, init) in enumerate(shapes):
+        if init == "ones":
             leaf = jnp.ones(logical, jnp.float32)
         else:
-            fan_in = 1 if path == "embed" else logical[-2]
+            _, fan_in = init
             leaf = jax.random.normal(jax.random.fold_in(key, i), logical,
                                      jnp.float32) / np.sqrt(fan_in)
         pad = [(0, p - n) for n, p in zip(logical, padded)]
@@ -76,20 +59,21 @@ def _make(key, *, shapes, dtype):
 
 def make(config: dict, seed: int, abstract_params: dict, dtype) -> dict:
     """The weights for ``seed`` in the program's layout; raises if that
-    layout holds a leaf this file does not know or shapes that do not
-    pad the published ones."""
+    layout holds a leaf the architecture module does not list or shapes
+    that do not pad the published ones."""
     abstract = _flatten(abstract_params)
-    logical = logical_shapes(config)
-    if set(abstract) != set(logical):
+    leaves = named(config, "arch").leaves(config)
+    if set(abstract) != set(leaves):
         raise ValueError(f"the program's parameters {sorted(abstract)} are "
-                         f"not the leaves {sorted(logical)}")
+                         f"not the leaves {sorted(leaves)}")
     shapes = []
-    for path in sorted(logical):
+    for path in sorted(leaves):
+        logical, init = leaves[path]
         padded = tuple(abstract[path].shape)
-        if len(padded) != len(logical[path]) or any(
-                p < n for p, n in zip(padded, logical[path])):
+        if len(padded) != len(logical) or any(
+                p < n for p, n in zip(padded, logical)):
             raise ValueError(f"{path}: program shape {padded} does not hold "
-                             f"{logical[path]}")
-        shapes.append((path, logical[path], padded))
+                             f"{logical}")
+        shapes.append((path, tuple(logical), padded, init))
     key = jax.random.key(np.random.default_rng(seed).integers(2**31))
     return _make(key, shapes=tuple(shapes), dtype=jnp.dtype(dtype))

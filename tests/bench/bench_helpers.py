@@ -24,10 +24,9 @@ def _load(*parts):
 
 
 def _cut_serve(config, traffic):
-    """A dense decoder at a tiny width and a mix of short requests."""
-    config.update(hidden_size=128, num_hidden_layers=2,
-                  num_attention_heads=4, num_key_value_heads=2, head_dim=32,
-                  intermediate_size=256, vocab_size=512)
+    """The model at its configuration's own ``test_size`` and a mix of
+    short requests."""
+    config.update(config["test_size"])
     traffic.update(clients=4, trace_seconds=1,
                    prompts={"lengths": [16, 32], "counts": [1, 1]},
                    outputs={"min": 4, "max": 12, "median": 6})
@@ -48,7 +47,8 @@ TINY = {"serve": _cut_serve, "sweep": _cut_sweep}
 
 
 def tiny_serve():
-    """internlm2's file at a tiny width, and the short mix cut to match."""
+    """internlm2's file at its test size, and the short mix cut to
+    match."""
     config = _load("bench", "configs", "internlm2-1.8b.json")
     traffic = _load("bench", "traffic", "short.json")
     _cut_serve(config, traffic)

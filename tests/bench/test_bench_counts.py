@@ -13,7 +13,7 @@ with open(os.path.join(ROOT, "bench", "peaks.json")) as f:
     V5E = json.load(f)["devices"]["TPU v5 lite"]
 TINY = dict(hidden_size=8, num_hidden_layers=2, num_attention_heads=4,
             num_key_value_heads=2, head_dim=2, intermediate_size=16,
-            vocab_size=10)
+            vocab_size=10, arch="bench/arch/dense.py")
 
 
 def test_matmul_params_of_internlm2():
